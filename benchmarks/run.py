@@ -10,6 +10,7 @@ Output: ``name,us_per_call,derived`` CSV (one line per benchmark).
   PYTHONPATH=src python -m benchmarks.run [--quick]
 """
 import argparse
+import os
 import math
 import time
 
@@ -339,7 +340,7 @@ def bench_cost_model(quick):
     res = LocalEngine().compile(sort_plan(n, M))(x)
     c = MRCost()
     c.absorb(res.stats)
-    hw = HardwareModel(chips=256)
+    hw = HardwareModel(chips=256, device_kind="TPU v5 lite")
     t = hw.shuffle_time(c)
     print(f"cost_model_T,{t*1e6:.1f},T=t+R*L+C/B on 256 chips"
           f"|R={c.rounds}|C={c.communication}")
@@ -977,6 +978,9 @@ def bench_scaling(quick):
         env = dict(os.environ)
         env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
         env["PYTHONPATH"] = os.path.join(repo, "src")
+        # Fake-device runs: pinned to the CPU, so a parent that holds the
+        # chip never starts a child that waits for it.
+        env["JAX_PLATFORMS"] = "cpu"
         proc = subprocess.run([sys.executable, "-c", _SCALING_CHILD],
                               capture_output=True, text=True, env=env,
                               timeout=600)
@@ -1006,7 +1010,7 @@ def bench_scaling(quick):
          "compute_s": r["micro"]["compute_s"],
          "overlap_efficiency": r["micro"]["efficiency"],
          "plans": r["plans"]} for r in rows]}
-    payload = {"bench": "scaling", "backend": jax.default_backend(),
+    payload = {"bench": "scaling", "backend": "cpu",
                "rounds": 16, "rows": rows, "series": series, "info": info}
     with open("BENCH_scaling.json", "w", encoding="utf-8") as f:
         json.dump(payload, f, indent=2)
@@ -1033,6 +1037,9 @@ def main() -> None:
                     help="run a single benchmark by name, e.g. "
                          "--only serve (matches bench_<name>)")
     args, _ = ap.parse_known_args()
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     benches = BENCHES
     if args.only:
         want = args.only if args.only.startswith("bench_") \

@@ -50,7 +50,7 @@ def paper_primitives():
     res = compile_plan(sort_plan(4096, M))(x)
     c.absorb(res.stats)
     assert bool(jnp.all(jnp.diff(res.values) >= 0))
-    hw = HardwareModel(chips=256)
+    hw = HardwareModel(chips=256, device_kind="TPU v5 lite")
     print(f"sample sort (§4.3): n=4096  rounds={c.rounds}  "
           f"communication={c.communication}")
     print(f"  cost-model wall time on 256 chips "

@@ -186,29 +186,59 @@ def tree_height(n_leaves: int, d: int) -> int:
     return max(1, math.ceil(math.log(n_leaves) / math.log(d)))
 
 
-# TPU v5e-class constants used when the abstract cost model is mapped onto the
-# target hardware (see DESIGN.md §2 and EXPERIMENTS.md §Roofline).
-PEAK_FLOPS_BF16 = 197e12          # per chip
-HBM_BW = 819e9                    # bytes/s per chip
-ICI_BW = 50e9                     # bytes/s per link
+class DevicePeaks(NamedTuple):
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops_bf16: float          # FLOP/s
+    hbm_bw: float              # bytes/s
+    ici_bw_per_link: float     # bytes/s per link
+    hbm_bytes: float
+
+
+#: Per-chip peaks keyed by ``jax.Device.device_kind``.  TPU v5e: Google Cloud
+#: documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+#: 1,600 Gbit/s of interconnect over 4 links).
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(flops_bf16=197e12, hbm_bw=819e9,
+                               ici_bw_per_link=50e9, hbm_bytes=16e9),
+}
+
 COLLECTIVE_LAUNCH_LATENCY = 1e-6  # ~ "L" for one shuffle hop on ICI
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """The published peaks of ``device_kind``; an unknown kind is an error,
+    never a default."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            f"to repro.core.costmodel.DEVICE_PEAKS") from None
 
 
 @dataclasses.dataclass(frozen=True)
 class HardwareModel:
-    """Maps the paper's (L, B) shuffle network onto a TPU mesh axis."""
+    """Maps the paper's (L, B) shuffle network onto a mesh of ``chips``
+    accelerators of ``device_kind`` (peaks from :data:`DEVICE_PEAKS`)."""
 
     chips: int
-    peak_flops: float = PEAK_FLOPS_BF16
-    hbm_bw: float = HBM_BW
-    ici_bw_per_link: float = ICI_BW
+    device_kind: str
     latency_s: float = COLLECTIVE_LAUNCH_LATENCY
+
+    def __post_init__(self):
+        device_peaks(self.device_kind)     # unknown kinds fail here
+
+    @property
+    def peaks(self) -> DevicePeaks:
+        return device_peaks(self.device_kind)
 
     def shuffle_time(self, cost: MRCost, bytes_per_item: int = 4) -> float:
         """Paper lower bound T = Omega(t + R*L + C/B) with B = aggregate ICI
         bandwidth and t charged at HBM streaming rate."""
-        agg_bw_items = self.chips * self.ici_bw_per_link / bytes_per_item
-        t_seconds = cost.internal_time * bytes_per_item / self.hbm_bw
+        agg_bw_items = (self.chips * self.peaks.ici_bw_per_link
+                        / bytes_per_item)
+        t_seconds = cost.internal_time * bytes_per_item / self.peaks.hbm_bw
         return (t_seconds
                 + cost.rounds * self.latency_s
                 + cost.communication / agg_bw_items)

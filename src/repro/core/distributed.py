@@ -31,26 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:                                    # jax >= 0.5 exports it at top level
-    shard_map = jax.shard_map
-except AttributeError:                  # 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-                  check_vma=None, check_rep=None, auto=None):
-        """Compat wrapper translating the modern jax.shard_map signature
-        (axis_names / check_vma) onto jax.experimental.shard_map
-        (auto / check_rep)."""
-        kwargs = {}
-        if auto is None and axis_names is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kwargs["auto"] = frozenset(auto)
-        check = check_vma if check_vma is not None else check_rep
-        if check is not None:
-            kwargs["check_rep"] = check
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kwargs)
+from .mrmodel import fifo_gather, fifo_sort
 
 
 # ---------------------------------------------------------------------------
@@ -63,18 +44,6 @@ class ShuffleOut(NamedTuple):
     dropped: jnp.ndarray       # scalar — items beyond per-pair capacity
 
 
-def _fifo_ranks(dests: jnp.ndarray, n_groups: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    n = dests.shape[0]
-    valid = (dests >= 0) & (dests < n_groups)
-    key = jnp.where(valid, dests, n_groups)
-    order = jnp.argsort(key, stable=True)
-    sorted_key = key[order]
-    first = jnp.searchsorted(sorted_key, sorted_key, side="left")
-    rank_sorted = jnp.arange(n, dtype=jnp.int32) - first.astype(jnp.int32)
-    rank = jnp.zeros((n,), jnp.int32).at[order].set(rank_sorted)
-    return rank, valid
-
-
 def shuffle_alltoall(dests: jnp.ndarray, payload: Any, axis_name: str,
                      capacity: int) -> ShuffleOut:
     """Route each local item to the shard named by ``dests`` (< 0 = none).
@@ -85,27 +54,28 @@ def shuffle_alltoall(dests: jnp.ndarray, payload: Any, axis_name: str,
     most n_shards * capacity items."""
     n_shards = lax.psum(1, axis_name)
     flat_dests = dests.reshape(-1)
-    rank, valid = _fifo_ranks(flat_dests, n_shards)
-    ok = valid & (rank < capacity)
-    dropped = jnp.sum(valid & ~ok)
-    d_idx = jnp.where(ok, flat_dests, n_shards)  # OOB -> dropped by scatter
-    s_idx = jnp.where(ok, rank, 0)
-
-    def pack(leaf):
-        flat = leaf.reshape((flat_dests.shape[0],) + leaf.shape[dests.ndim:])
-        buf = jnp.zeros((n_shards, capacity) + flat.shape[1:], flat.dtype)
-        return buf.at[d_idx, s_idx].set(flat, mode="drop")
-
-    send = jax.tree_util.tree_map(pack, payload)
-    mask = jnp.zeros((n_shards, capacity), bool).at[d_idx, s_idx].set(
-        ok, mode="drop")
+    n = flat_dests.shape[0]
+    valid = (flat_dests >= 0) & (flat_dests < n_shards)
+    order, sorted_key = fifo_sort(jnp.where(valid, flat_dests, n_shards))
+    leaves, treedef = jax.tree_util.tree_flatten(payload)
+    boxes, _, counts = fifo_gather(
+        order, sorted_key,
+        [l.reshape((n,) + l.shape[dests.ndim:]) for l in leaves],
+        n_shards, capacity)
+    send = jax.tree_util.tree_unflatten(treedef, boxes)
+    dropped = jnp.sum(jnp.maximum(counts - capacity, 0))
 
     def a2a(leaf):
         return lax.all_to_all(leaf, axis_name, split_axis=0, concat_axis=0,
                               tiled=True)
 
     recv = jax.tree_util.tree_map(a2a, send)
-    recv_mask = a2a(mask)
+    # Every box is a FIFO prefix: send its fill count and rebuild the mask
+    # on arrival (an all_to_all of the mask itself compiles in minutes and
+    # gigabytes of host memory at chip sizes).
+    recv_fill = a2a(jnp.minimum(counts, capacity))
+    recv_mask = (jnp.arange(capacity, dtype=jnp.int32)[None, :]
+                 < recv_fill[:, None])
     return ShuffleOut(payload=recv, valid=recv_mask,
                       dropped=lax.psum(dropped, axis_name))
 

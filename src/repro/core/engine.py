@@ -17,7 +17,7 @@ interchangeable backends (DESIGN.md §2):
   ================== ========================== ===========================
 
 Orthogonally to the backend, the Shuffle hot loop has two implementations
-(``shuffle_impl=``): the ``"dense"`` jnp argsort-scatter of
+(``shuffle_impl=``): the ``"dense"`` jnp sort-and-gather of
 :func:`repro.core.mrmodel.shuffle`, and the ``"kernel"`` Pallas composition
 of :func:`repro.core.kshuffle.kernel_shuffle` (bincount → prefix_scan →
 bitonic_sort; DESIGN.md §7).  ``get_engine("pallas")`` is the registered
@@ -73,11 +73,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import AxisType
 
 from .costmodel import CostAccum, MRCost, RoundStats
 from .mrmodel import Mailbox, Payload, RoundFn, make_mailbox
 from .mrmodel import shuffle as _dense_shuffle
 from ..obs import NULL_TRACER, round_event as _round_event
+from ..obs.trace import not_tracing
 
 
 class RoundProgram(NamedTuple):
@@ -374,8 +376,8 @@ class LocalEngine(MREngine):
     ``shuffle_impl`` selects the Shuffle hot loop (bit-identical semantics,
     pinned by the conformance suite):
 
-    - ``"dense"`` (default): :func:`repro.core.mrmodel.shuffle` — stable
-      jnp argsort by destination + rank-addressed scatter;
+    - ``"dense"`` (default): :func:`repro.core.mrmodel.shuffle` — one
+      stable sort by destination, then a gather of each node's run;
     - ``"kernel"``: :func:`repro.core.kshuffle.kernel_shuffle` — the
       multi-tile radix Pallas composition, fused bincount_tiles →
       tile-local bitonic_sort (``interpret=True`` off TPU).
@@ -563,7 +565,8 @@ class ShardedEngine(MREngine):
                  overlap: bool = True):
         super().__init__(tracer=tracer)
         if mesh is None:
-            mesh = jax.make_mesh((jax.device_count(),), (axis_name,))
+            mesh = jax.make_mesh((jax.device_count(),), (axis_name,),
+                                 axis_types=(AxisType.Auto,))
         if axis_name not in mesh.axis_names:
             raise ValueError(f"axis {axis_name!r} not in mesh {mesh.axis_names}")
         if shuffle_impl not in ("dense", "kernel"):
@@ -595,7 +598,7 @@ class ShardedEngine(MREngine):
         send-side global stats (items_sent, max_sent).  Independent of
         ``capacity`` and of the phase-2 scatter implementation, so one hop
         lowering is shared by every stage with the same send shape."""
-        from .distributed import keyed_hop, shard_map
+        from .distributed import keyed_hop
 
         axis = self.axis_name
 
@@ -621,8 +624,8 @@ class ShardedEngine(MREngine):
         P = jax.sharding.PartitionSpec
         in_specs = (P(axis),) + (P(axis),) * n_leaves
         out_specs = (P(axis), [P(axis)] * n_leaves, P(), P())
-        return jax.jit(shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                                 out_specs=out_specs))
+        return jax.jit(jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                                     out_specs=out_specs))
 
     def _build_scatter(self, n_nodes: int, capacity: int, n_leaves: int,
                        use_kernel: bool):
@@ -632,8 +635,6 @@ class ShardedEngine(MREngine):
         output buffers are donated in — they are dead after this call, so
         XLA may alias them instead of copying, and the scatter launches as
         its own program no longer barriered behind the collective."""
-        from .distributed import shard_map
-
         axis = self.axis_name
         local_v = n_nodes // self.n_shards
         local_shuffle = self._local_shuffle if use_kernel else _dense_shuffle
@@ -648,14 +649,11 @@ class ShardedEngine(MREngine):
         P = jax.sharding.PartitionSpec
         in_specs = (P(axis),) + (P(axis),) * n_leaves
         out_specs = ([P(axis)] * n_leaves, P(axis), P(), P())
-        kwargs = {}
-        if use_kernel:
-            # jax 0.4.x has no replication rule for pallas_call; the body's
-            # outputs carry explicit per-shard specs, so skipping the check
-            # is sound.
-            kwargs["check_rep"] = False
-        fn = shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                       out_specs=out_specs, **kwargs)
+        # pallas_call outputs carry no varying-axes annotation; the body's
+        # outputs have explicit per-shard specs, so skipping the check is
+        # sound.
+        fn = jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=not use_kernel)
         donate = ()
         if self.mesh.devices.flat[0].platform != "cpu":
             # Donation is unimplemented on the CPU backend (warning spam);
@@ -828,7 +826,7 @@ class ShardedEngine(MREngine):
         marks each issued round without reading any device value)."""
         acc = accum if accum is not None else CostAccum.zero()
         tr = self.tracer
-        live = tr.enabled and jax.core.trace_state_clean()
+        live = tr.enabled and not_tracing()
         clock = tr.clock
         t_start = clock() if live else 0.0
         calibrated = not live

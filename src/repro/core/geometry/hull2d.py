@@ -33,7 +33,7 @@ import jax.numpy as jnp
 
 from ..costmodel import CostAccum, MRCost, log_M, tree_height
 from ..plan import Plan, account_stage, entry_stage, round_stage
-from ..sortmr import pivot_sample_size, quantile_splitters
+from ..sortmr import default_oversample, pivot_sample_size, quantile_splitters
 from .chain import hull_of_runs
 
 
@@ -45,7 +45,8 @@ class EngineHullResult(NamedTuple):
     stats: CostAccum      # valid iff stats.dropped == 0
 
 
-def hull2d_plan(n: int, M: int, *, oversample: int = 8, slack: float = 3.0,
+def hull2d_plan(n: int, M: int, *, oversample: Optional[int] = None,
+                slack: float = 3.0,
                 n_nodes: Optional[int] = None, align=None,
                 shape: bool = True) -> Plan:
     """2-D convex hull (CCW from the lexicographic minimum) as a plan
@@ -89,6 +90,8 @@ def hull2d_plan(n: int, M: int, *, oversample: int = 8, slack: float = 3.0,
             V = int(align(V))
     a = max(2, M_eff // 2)                       # merge-tree arity
     n_levels = tree_height(V, a) if V > 1 else 0
+    if oversample is None:
+        oversample = default_oversample(V, slack)
     s = pivot_sample_size(n, V, oversample)      # static, = runtime sample
     piv_rounds = max(1, log_M(max(s, 2), M_eff))
     cap0 = min(n, max(1, int(math.ceil(slack * n / V))))
@@ -169,7 +172,8 @@ def hull2d_plan(n: int, M: int, *, oversample: int = 8, slack: float = 3.0,
 def convex_hull_2d_mr(points: jnp.ndarray, M: int, *, engine=None,
                       key: Optional[jax.Array] = None,
                       n_nodes: Optional[int] = None,
-                      slack: float = 3.0, oversample: int = 8
+                      slack: float = 3.0,
+                      oversample: Optional[int] = None
                       ) -> EngineHullResult:
     """Deprecated wrapper over :func:`hull2d_plan`: builds the plan,
     compiles it on ``engine`` (cached per fingerprint) and runs it on
@@ -208,7 +212,7 @@ def convex_hull_2d(points, M: int, *, engine=None,
     return np.asarray(res.points, np.float64)[:h]
 
 
-def hull_round_bound(n: int, M: int, oversample: int = 8,
+def hull_round_bound(n: int, M: int, oversample: Optional[int] = None,
                      n_nodes: Optional[int] = None) -> int:
     """Concrete ceiling for the engine hull's round count: pivot-sort rounds
     + entry shuffle + merge-tree height + finalize (the paper's O(log_M N)).
@@ -221,7 +225,9 @@ def hull_round_bound(n: int, M: int, oversample: int = 8,
     """
     M_eff = max(2, int(M))
     V = int(n_nodes) if n_nodes is not None else max(1, -(-n // M_eff))
-    s = min(n, max(2, V * oversample))
+    if oversample is None:
+        oversample = default_oversample(V, 3.0)   # hull2d_plan's slack
+    s = pivot_sample_size(n, V, oversample)
     a = max(2, M_eff // 2)
     return (max(1, log_M(max(s, 2), M_eff)) + 1
             + (tree_height(V, a) if V > 1 else 0) + 1)

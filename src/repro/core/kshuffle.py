@@ -22,9 +22,9 @@ paper's "one reducer sorts its bucket"), so the composite key is segmented
 per tile — ``dest * tile + local_src`` with local_src < tile — and stays
 int32 even when the old global key ``dest * n + src`` would overflow.  The
 old size cliffs (single-VMEM-tile ``n <= 2^18``; int32 key space
-``n_nodes·n + n − 1 < 2^31 − 1``) are gone: tiles shrink as ``n_nodes``
-grows and the tile count T is unbounded, so entry-level shapes route
-through the kernel (see :func:`kernel_fits` for the two remaining guards).
+``n_nodes·n + n − 1 < 2^31 − 1``) are gone: the tile count T is unbounded,
+so entry-level shapes route through the kernel (see :func:`kernel_fits`
+for the guards that remain, all VMEM or HBM bounds of one call).
 
 The result is **bit-identical** to the dense :func:`repro.core.mrmodel.
 shuffle` — same mailbox payload/validity, same :class:`RoundStats` (including
@@ -32,11 +32,13 @@ the drop count), same FIFO-within-source order — which the conformance suite
 (``tests/test_conformance.py``) and the differential fuzz suite
 (``tests/test_kernel_shuffle.py``, ``tests/test_properties.py``) pin.
 
-Off-TPU (the jax 0.4.37 CPU CI) the kernels run with ``interpret=True`` —
-the kernel bodies execute as traced jnp with the identical control flow the
-Mosaic lowering compiles, so the parity tests cover the TPU code path's
-semantics; only the timing differs.  Select this path per engine with
-``LocalEngine(shuffle_impl="kernel")`` / ``get_engine("pallas")``.
+On a TPU the kernels compile with Mosaic; on the CPU backend (the test
+suite) they run with ``interpret=True`` — the kernel bodies execute as
+traced jnp with the identical control flow the Mosaic lowering compiles, so
+the parity tests cover the TPU code path's semantics; only the timing
+differs.  ``tests/test_tpu_compile.py`` compiles them for a described v5e.
+Select this path per engine with ``LocalEngine(shuffle_impl="kernel")`` /
+``get_engine("pallas")``.
 
     >>> import numpy as np, jax.numpy as jnp
     >>> box, stats = kernel_shuffle(jnp.array([1, 0, 1, 1], jnp.int32),
@@ -47,7 +49,7 @@ semantics; only the timing differs.  Select this path per engine with
     1
     >>> kernel_fits((1 << 18) + 1, 64)     # past the old single-tile cliff
     True
-    >>> kernel_fits(40000, 2 ** 16)        # past the old int32-key cliff
+    >>> kernel_fits(300000, 8191)          # past the old int32-key cliff
     True
 """
 from __future__ import annotations
@@ -58,23 +60,19 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import ops as _kops
+from ..kernels.bincount import MAX_BUCKETS
+from ..kernels.bitonic_sort import MAX_ROW_WIDTH
 from .costmodel import RoundStats
 from .mrmodel import Mailbox, Payload, materialize_mailbox
 
-_INT32_MAX = 2**31 - 1
 #: the OLD single-tile cliff (PR 3-7): the bitonic network ran the whole row
-#: as one VMEM tile of at most this many elements.  It survives only as the
-#: per-launch row-block budget inside kernels.bitonic_sort; kernel_fits no
-#: longer depends on n at all.
+#: as one VMEM tile of at most this many elements; kernel_fits no longer
+#: depends on n at all.
 _MAX_SORT_N = 1 << 18
 #: default within-tile sort width (one bitonic network per tile)
 _TILE_N = 4096
-#: below this derived tile width the per-tile sort degenerates — bail dense
-_MIN_TILE_N = 8
-#: per-launch budget for the (tile, n_nodes+1) one-hot count matrix — the
-#: VMEM footprint of one bincount_tiles grid step; tiles shrink to honor it
-_ONEHOT_BUDGET = 1 << 24
-#: total-element budget for each (T, n_nodes+1) count matrix in HBM
+#: total-element budget for each (T, n_nodes+1) count matrix in HBM (three
+#: such int32 matrices at the edge take 384 MiB of a v5e's 16 GB)
 _COUNTS_BUDGET = 1 << 25
 
 
@@ -119,27 +117,25 @@ class RouteLog:
 route_log = RouteLog()
 
 
-def _tile_width(n_nodes: int, tile_n: Optional[int] = None) -> int:
-    """Within-tile sort width for a shuffle into ``n_nodes`` buckets.
-
-    The derived width is the largest power of two honoring (a) the default
-    ``_TILE_N``, (b) the one-hot count matrix budget ``tile · (V+1) <=
-    _ONEHOT_BUDGET``, and (c) the segmented int32 key space ``(V+1) · tile
-    <= 2^31 − 1`` (the sentinel bucket V sorts last, strictly below the
-    bitonic network's int32-max padding).  An explicit ``tile_n`` overrides
-    the derivation (the differential fuzz suite uses tiny tiles to cross
-    the multi-tile boundary with small inputs).
-    """
-    if tile_n is not None:
-        if tile_n < 1:
-            raise ValueError(f"tile_n must be >= 1, got {tile_n}")
-        return tile_n
-    limit = min(_TILE_N, _ONEHOT_BUDGET // (n_nodes + 1),
-                _INT32_MAX // (n_nodes + 1))
-    t = 1
-    while t * 2 <= limit:
-        t *= 2
-    return t
+def _misfit(n: int, n_nodes: int, tile_n: Optional[int]) -> Optional[str]:
+    """Why a shuffle of ``n`` items into ``n_nodes`` nodes cannot take the
+    kernel path, or None when it can."""
+    tile = _TILE_N if tile_n is None else tile_n
+    if tile < 1:
+        raise ValueError(f"tile_n must be >= 1, got {tile_n}")
+    if n_nodes > MAX_BUCKETS:
+        return (f"n_nodes={n_nodes} exceeds the bincount_tiles one-hot VMEM "
+                f"budget ({MAX_BUCKETS} buckets); use the dense shuffle "
+                f"(LocalEngine(shuffle_impl='dense')) for this node count")
+    if tile > MAX_ROW_WIDTH:
+        return (f"tile_n={tile} exceeds the bitonic_sort row-block VMEM "
+                f"budget ({MAX_ROW_WIDTH} keys per row)")
+    n_tiles = -(-n // tile) if n else 1
+    if n_tiles * (n_nodes + 1) > _COUNTS_BUDGET:
+        return (f"tile-count matrix {n_tiles}x{n_nodes + 1} exceeds the "
+                f"counts budget ({_COUNTS_BUDGET}); use the dense shuffle "
+                f"(LocalEngine(shuffle_impl='dense')) for this size")
+    return None
 
 
 def kernel_fits(n: int, n_nodes: int, tile_n: Optional[int] = None) -> bool:
@@ -147,46 +143,33 @@ def kernel_fits(n: int, n_nodes: int, tile_n: Optional[int] = None) -> bool:
     the multi-tile kernel path's guards.
 
     The old cliffs — ``n`` past one VMEM tile, composite key past int32 —
-    are gone: the sort is tiled and the keys are segmented per tile.  Two
-    guards remain, both functions of one *call's* shape:
+    are gone: the sort is tiled and the keys are segmented per tile.  The
+    guards that remain are functions of one *call's* shape, each a bound
+    the TPU compiler enforces (``tests/test_tpu_compile.py`` compiles at
+    the edges):
 
-    - the derived tile width must stay >= ``_MIN_TILE_N`` (it shrinks as
-      ``n_nodes`` grows to keep one-hot counting in VMEM and segmented keys
-      in int32, so ~2M+ destination nodes bail to dense);
-    - the (T, n_nodes+1) count matrices must fit ``_COUNTS_BUDGET``
-      elements (T = ceil(n / tile)).
+    - ``n_nodes`` within the one-hot VMEM budget of ``bincount_tiles``
+      (``repro.kernels.bincount.MAX_BUCKETS``);
+    - an explicit ``tile_n`` within one VMEM row block of ``bitonic_sort``
+      (the default tile is ``_TILE_N``);
+    - the (T, n_nodes+1) count matrices within ``_COUNTS_BUDGET`` elements
+      (T = ceil(n / tile)).
 
-    In a shape-scheduled program (DESIGN.md §9) the predicate is re-derived
-    per stage from that stage's (V_r, M_r) footprint — both
+    Together the first two keep the segmented keys ``(n_nodes+1)·tile``
+    inside int32.  In a shape-scheduled program (DESIGN.md §9) the predicate
+    is re-derived per stage from that stage's (V_r, M_r) footprint — both
     ``LocalEngine(shuffle_impl="kernel")`` and ``ShardedEngine``'s
     per-shard scatter route each call through it.  The strict
     :func:`kernel_shuffle` guards raise on exactly ``not kernel_fits(...)``
     — one predicate, two policies.
     """
-    tile = _tile_width(n_nodes, tile_n)
-    if tile < _MIN_TILE_N and tile_n is None:
-        return False
-    if (n_nodes + 1) * tile > _INT32_MAX:   # explicit tile_n past key space
-        return False
-    n_tiles = -(-n // tile) if n else 1
-    return n_tiles * (n_nodes + 1) <= _COUNTS_BUDGET
+    return _misfit(n, n_nodes, tile_n) is None
 
 
 def _check_fits(n: int, n_nodes: int, tile_n: Optional[int]) -> None:
-    tile = _tile_width(n_nodes, tile_n)
-    if ((tile < _MIN_TILE_N and tile_n is None)
-            or (n_nodes + 1) * tile > _INT32_MAX):
-        raise ValueError(
-            f"kernel_shuffle: n_nodes={n_nodes} shrinks the per-tile "
-            f"segmented key space dest*tile+src below tile={tile} < "
-            f"{_MIN_TILE_N} (or past int32); use the dense shuffle "
-            f"(LocalEngine(shuffle_impl='dense')) for this node count")
-    n_tiles = -(-n // tile) if n else 1
-    if n_tiles * (n_nodes + 1) > _COUNTS_BUDGET:
-        raise ValueError(
-            f"kernel_shuffle: tile-count matrix {n_tiles}x{n_nodes + 1} "
-            f"exceeds the counts budget ({_COUNTS_BUDGET}); use the dense "
-            f"shuffle (LocalEngine(shuffle_impl='dense')) for this size")
+    why = _misfit(n, n_nodes, tile_n)
+    if why is not None:
+        raise ValueError(f"kernel_shuffle: {why}")
 
 
 def kernel_shuffle(dests: jnp.ndarray, payload: Payload, n_nodes: int,
@@ -210,8 +193,7 @@ def kernel_shuffle(dests: jnp.ndarray, payload: Payload, n_nodes: int,
     arrival rank is then ``cross_tile_prefix + in-tile rank``, and a
     rank-addressed scatter materializes the (V, capacity) mailbox.
 
-    ``tile_n`` overrides the derived tile width (testing/tuning knob; must
-    keep ``(n_nodes+1)·tile_n`` within int32).
+    ``tile_n`` overrides the default tile width (testing/tuning knob).
     """
     dests = jnp.asarray(dests)
     flat_dest = dests.reshape(-1).astype(jnp.int32)
@@ -223,7 +205,7 @@ def kernel_shuffle(dests: jnp.ndarray, payload: Payload, n_nodes: int,
         counts = jnp.zeros((n_nodes,), jnp.int32)
         rank = jnp.zeros((0,), jnp.int32)
     else:
-        tile = _tile_width(n_nodes, tile_n)
+        tile = _TILE_N if tile_n is None else tile_n
         n_tiles = -(-n // tile)
         # Source-order tiling; the tail pads with the "no item" sentinel.
         dtile = jnp.pad(flat_dest, (0, n_tiles * tile - n),

@@ -64,14 +64,12 @@ def materialize_mailbox(dests: jnp.ndarray, payload: Payload,
                         flat_dest: jnp.ndarray, valid: jnp.ndarray,
                         rank: jnp.ndarray, n_nodes: int,
                         capacity: int) -> Tuple[Mailbox, jnp.ndarray]:
-    """Shared placement tail of both shuffle implementations (dense and
-    :func:`repro.core.kshuffle.kernel_shuffle`): keep items whose arrival
-    ``rank`` fits ``capacity``, scatter payload + validity into the
-    (V, capacity) mailbox (``mode='drop'`` discards out-of-range writes),
-    and compute the per-source-node ``max_sent`` stat.  The DESIGN.md §7
-    bit-identity contract between the two implementations lives here —
-    they differ only in how ``rank`` (and the remaining stats) are
-    computed."""
+    """Placement tail of :func:`repro.core.kshuffle.kernel_shuffle`: keep
+    items whose arrival ``rank`` fits ``capacity``, scatter payload +
+    validity into the (V, capacity) mailbox (``mode='drop'`` discards
+    out-of-range writes), and compute the per-source-node ``max_sent``
+    stat.  The dense :func:`shuffle` places the same items in the same
+    slots by gathering from its sorted order (DESIGN.md §7)."""
     n = flat_dest.shape[0]
     in_range = valid & (rank < capacity)
     dest_idx = jnp.where(in_range, flat_dest, -1)
@@ -85,15 +83,57 @@ def materialize_mailbox(dests: jnp.ndarray, payload: Payload,
     new_payload = jax.tree_util.tree_map(place, payload)
     new_valid = jnp.zeros((n_nodes, capacity), bool).at[dest_idx, slot_idx].set(
         in_range, mode="drop")
-    if dests.ndim >= 2 and n:
-        sent_per_node = jnp.sum(valid.reshape(dests.shape[0], -1), axis=1)
-        max_sent = jnp.max(sent_per_node)
-    else:
-        # Empty (V, M) sends have no source nodes (reshape(-1) over a
-        # zero-size leading dim is ill-posed anyway): max_sent = 0, matching
-        # the reference backend's max(initial=0).
-        max_sent = jnp.array(0 if dests.ndim >= 2 else 1, jnp.int32)
-    return Mailbox(payload=new_payload, valid=new_valid), max_sent
+    return (Mailbox(payload=new_payload, valid=new_valid),
+            max_sent_of(dests, valid))
+
+
+def max_sent_of(dests: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
+    """Most items any source node sent: rows of a (V, M, ...) ``dests``;
+    a 1-D ``dests`` is one source (1)."""
+    if dests.ndim >= 2 and valid.shape[0]:
+        return jnp.max(jnp.sum(valid.reshape(dests.shape[0], -1), axis=1))
+    # Empty (V, M) sends have no source nodes (reshape(-1) over a zero-size
+    # leading dim is ill-posed anyway): max_sent = 0, matching the
+    # reference backend's max(initial=0).
+    return jnp.array(0 if dests.ndim >= 2 else 1, jnp.int32)
+
+
+def fifo_sort(sort_key: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Stable sort of items by integer ``sort_key``: returns ``(order,
+    sorted_key)``, the source index of each sorted position and the keys in
+    sorted order.  Equal keys keep source order, so each key's run lists
+    its items FIFO."""
+    iota = jnp.arange(sort_key.shape[0], dtype=jnp.int32)
+    sorted_key, order = jax.lax.sort((sort_key.astype(jnp.int32), iota),
+                                     num_keys=1, is_stable=True)
+    return order, sorted_key
+
+
+def fifo_gather(order: jnp.ndarray, sorted_key: jnp.ndarray, leaves,
+                n_groups: int, capacity: int):
+    """Read (n_groups, capacity) FIFO boxes out of :func:`fifo_sort`'s order.
+
+    Slot (g, r) holds the r-th item of key g, for r below both the number
+    of such items and ``capacity``; other slots are zero and invalid.
+    Returns (boxes, valid, counts) with ``counts[g]`` the items of key g.
+    The sorted order makes every box a contiguous run, so placement reads
+    each run with one gather and no rank travels back to source order."""
+    n = order.shape[0]
+    bounds = jnp.searchsorted(
+        sorted_key, jnp.arange(n_groups + 1, dtype=jnp.int32), side="left"
+    ).astype(jnp.int32)
+    start, counts = bounds[:-1], bounds[1:] - bounds[:-1]
+    slot = jnp.arange(capacity, dtype=jnp.int32)
+    valid = slot[None, :] < jnp.minimum(counts, capacity)[:, None]
+    if n == 0:
+        return ([jnp.zeros((n_groups, capacity) + l.shape[1:], l.dtype)
+                 for l in leaves], valid, counts)
+    src = order[jnp.minimum(start[:, None] + slot[None, :], n - 1)]
+
+    def place(leaf):
+        ok = valid.reshape(valid.shape + (1,) * (leaf.ndim - 1))
+        return jnp.where(ok, leaf[src], jnp.zeros((), leaf.dtype))
+    return [place(l) for l in leaves], valid, counts
 
 
 def shuffle(dests: jnp.ndarray, payload: Payload, n_nodes: int,
@@ -106,34 +146,28 @@ def shuffle(dests: jnp.ndarray, payload: Payload, n_nodes: int,
     ``0..capacity-1``; items ranked past ``capacity`` at their destination are
     dropped and counted.
 
-    This is the dense jnp implementation (stable argsort + rank-addressed
-    scatter) and the semantics oracle for the Pallas-composed counterpart,
+    This is the dense jnp implementation (one stable sort by destination,
+    then a gather of each node's run into its slots) and the semantics
+    oracle for the Pallas-composed counterpart,
     :func:`repro.core.kshuffle.kernel_shuffle` (DESIGN.md §7).
     """
     flat_dest = dests.reshape(-1)
     n = flat_dest.shape[0]
     valid = flat_dest >= 0
-    # Stable sort by destination; invalid items sort to the end.
-    sort_key = jnp.where(valid, flat_dest, n_nodes)
-    order = jnp.argsort(sort_key, stable=True)
-    sorted_dest = sort_key[order]
-    # Rank of each item within its destination segment.
-    first_occurrence = jnp.searchsorted(sorted_dest, sorted_dest, side="left")
-    rank_sorted = jnp.arange(n, dtype=jnp.int32) - first_occurrence.astype(jnp.int32)
-    # Scatter back to source order.
-    rank = jnp.zeros((n,), jnp.int32).at[order].set(rank_sorted)
-
-    box, max_sent = materialize_mailbox(dests, payload, flat_dest, valid,
-                                        rank, n_nodes, capacity)
-    recv_counts = jnp.bincount(jnp.where(valid, flat_dest, 0),
-                               weights=valid.astype(jnp.int32),
-                               length=n_nodes)
+    # Invalid items sort to the end, as the sentinel node n_nodes.
+    order, sorted_key = fifo_sort(jnp.where(valid, flat_dest, n_nodes))
+    leaves, treedef = jax.tree_util.tree_flatten(payload)
+    flat_leaves = [l.reshape((n,) + l.shape[dests.ndim:]) for l in leaves]
+    boxes, box_valid, counts = fifo_gather(order, sorted_key, flat_leaves,
+                                           n_nodes, capacity)
     stats = ShuffleStats(
         items_sent=jnp.sum(valid),
-        max_sent=max_sent,
-        max_received=jnp.max(recv_counts).astype(jnp.int32),
-        dropped=jnp.sum(valid & (rank >= capacity)),
+        max_sent=max_sent_of(dests, valid),
+        max_received=jnp.max(counts).astype(jnp.int32),
+        dropped=jnp.sum(jnp.maximum(counts - capacity, 0)),
     )
+    box = Mailbox(payload=jax.tree_util.tree_unflatten(treedef, boxes),
+                  valid=box_valid)
     return box, stats
 
 

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from .costmodel import CostAccum
 from .mrmodel import Mailbox
 from ..obs import NULL_TRACER, plan_token, round_event as _round_event
+from ..obs.trace import not_tracing
 
 
 class PlanStage(NamedTuple):
@@ -246,7 +247,7 @@ def execute_plan(plan: Plan, engine, inputs: Tuple, key=None,
         state = _apply_stages(plan, engine, state, 0, checkpointer)
     else:
         tr = getattr(engine, "tracer", NULL_TRACER)
-        if tr.enabled and jax.core.trace_state_clean():
+        if tr.enabled and not_tracing():
             # Eager traced execution: per-stage spans carry the declared
             # schedule next to the measured CostAccum deltas (reading them
             # is a host sync — the opt-in cost of tracing).  Under jit the

@@ -528,6 +528,7 @@ def elastic_engine(n_shards: int, axis_name: str = "nodes",
             f"{len(devs)} devices are healthy — refusing to silently "
             f"shrink the resume topology")
     mesh = jax.make_mesh((int(n_shards),), (axis_name,),
+                         axis_types=(jax.sharding.AxisType.Auto,),
                          devices=devs[:int(n_shards)])
     return ShardedEngine(axis_name=axis_name, mesh=mesh,
                          shuffle_impl=shuffle_impl)

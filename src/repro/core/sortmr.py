@@ -130,6 +130,27 @@ def pivot_sample_size(n: int, n_buckets: int, oversample: int) -> int:
     return int(min(n, max(2, n_buckets * oversample)))
 
 
+#: Target probability that any bucket of a sample sort overflows.
+_OVERFLOW_TARGET = 1e-9
+
+
+def default_oversample(n_buckets: int, slack: float) -> int:
+    """Pivot samples per bucket that make the w.h.p. overflow event
+    negligible: at least 8, and enough that all ``n_buckets`` buckets stay
+    within ``slack`` times their mean share with probability 1 - 1e-9.
+
+    A bucket between sample quantiles k samples apart holds a share of
+    about Gamma(k)/s; its Chernoff tail P(Gamma(k) > a k) <=
+    exp(-k (a - 1 - ln a)) at a = slack, union-bounded over the buckets,
+    gives k.  A fixed k = 8 overflows a 4096-bucket sort in about one run
+    in five."""
+    rate = slack - 1.0 - math.log(slack) if slack > 1 else 0.0
+    if n_buckets <= 1 or rate <= 0:
+        return 8
+    need = (math.log(n_buckets) - math.log(_OVERFLOW_TARGET)) / rate
+    return max(8, math.ceil(need))
+
+
 def quantile_splitters(x: jnp.ndarray, n_buckets: int, oversample: int,
                        key: jax.Array) -> Tuple[jnp.ndarray, int]:
     """§4.3 pivot stage: the ``n_buckets - 1`` sample-quantile splitters of a
@@ -144,11 +165,13 @@ def quantile_splitters(x: jnp.ndarray, n_buckets: int, oversample: int,
     n = x.shape[0]
     s = pivot_sample_size(n, n_buckets, oversample)
     sample = jnp.sort(x[jax.random.permutation(key, n)[:s]])
-    return sample[(jnp.arange(1, n_buckets) * s) // n_buckets], s
+    # Static host indices: (n_buckets - 1) * s passes int32 at chip sizes.
+    idx = np.arange(1, n_buckets, dtype=np.int64) * s // n_buckets
+    return sample[idx], s
 
 
 def sort_plan(n: int, M: int, *, dtype=jnp.float32, levels: int = 1,
-              oversample: int = 8, slack: float = 3.0,
+              oversample: Optional[int] = None, slack: float = 3.0,
               n_nodes: Optional[int] = None, align=None,
               shape: bool = True) -> Plan:
     """§4.3 sample sort as a plan builder (DESIGN.md §3 and §8).
@@ -160,7 +183,8 @@ def sort_plan(n: int, M: int, *, dtype=jnp.float32, levels: int = 1,
     bucket in ``levels`` shuffles; one reducer-local sort round (the "keep"
     primitive) then orders each bucket.  Splitters are the V-1 sample
     quantiles of a Theta(V * oversample) random sample — the paper's pivot
-    stage, accounted as its O(log_M) rounds.
+    stage, accounted as its O(log_M) rounds.  ``oversample`` defaults to
+    :func:`default_oversample` of (V, slack).
 
     Everything here is static — shapes, capacities, the stage table — so
     the plan is built **without touching data**; inputs ``(x,)`` arrive at
@@ -196,6 +220,8 @@ def sort_plan(n: int, M: int, *, dtype=jnp.float32, levels: int = 1,
         if align is not None:
             V = int(align(V))
     B = max(2, math.ceil(V ** (1.0 / levels))) if V > 1 else 1
+    if oversample is None:
+        oversample = default_oversample(V, slack)
     s = pivot_sample_size(n, V, oversample)       # static, = runtime sample
     piv_rounds = max(1, log_M(max(s, 2), M_eff))
     fingerprint = ("sort", n, M, str(dtype), levels, oversample,
@@ -269,17 +295,21 @@ def sort_plan(n: int, M: int, *, dtype=jnp.float32, levels: int = 1,
     stages.append(account_stage("output", ((n, 1),)))   # leaves -> output
 
     def epilogue(state):
-        # Output assembly: bucket-major compaction (valid slots are a FIFO
-        # prefix per node, so position = bucket offset + slot).
+        # Output assembly: bucket-major compaction.  Valid slots are a FIFO
+        # prefix per node, so output position i is slot i - offset of the
+        # bucket whose run of positions holds i: a gather, not a scatter.
         box = state.box
         valid = jnp.asarray(box.valid)
         payload = jnp.asarray(box.payload)
-        counts = jnp.sum(valid, axis=1)
-        offsets = jnp.cumsum(counts) - counts
-        slot = jnp.arange(valid.shape[1], dtype=jnp.int32)[None, :]
-        pos = jnp.where(valid, offsets[:, None] + slot, n)
-        out = jnp.zeros((n,), dtype).at[pos.reshape(-1)].set(
-            payload.reshape(-1), mode="drop")
+        counts = jnp.sum(valid, axis=1, dtype=jnp.int32)
+        ends = jnp.cumsum(counts)
+        pos = jnp.arange(n, dtype=jnp.int32)
+        bucket = jnp.minimum(jnp.searchsorted(ends, pos, side="right"),
+                             valid.shape[0] - 1)
+        slot = jnp.minimum(pos - (ends[bucket] - counts[bucket]),
+                           valid.shape[1] - 1)
+        out = jnp.where(pos < ends[-1], payload[bucket, slot],
+                        jnp.zeros((), dtype))
         return EngineSortResult(values=out, stats=state.accum)
 
     return Plan(name="sort", fingerprint=fingerprint, n_nodes=V,
@@ -292,7 +322,7 @@ def sort_plan(n: int, M: int, *, dtype=jnp.float32, levels: int = 1,
 def sample_sort_mr(x: jnp.ndarray, M: int, *, engine=None,
                    key: Optional[jax.Array] = None,
                    n_nodes: Optional[int] = None,
-                   levels: int = 1, oversample: int = 8,
+                   levels: int = 1, oversample: Optional[int] = None,
                    slack: float = 3.0) -> EngineSortResult:
     """Deprecated wrapper over :func:`sort_plan`: builds the plan, compiles
     it on ``engine`` (cached per fingerprint) and runs it on ``x``.  Prefer

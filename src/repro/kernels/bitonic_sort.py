@@ -3,18 +3,23 @@
 The paper's sample sort (§4.3) bottoms out when a bucket fits one reducer
 (<= M items); that reducer then sorts locally.  On TPU "one reducer" is one
 VMEM tile, and the TPU-native local sort is a bitonic network: data-oblivious
-compare-exchange stages expressed as dense reshape/min/max — no gathers, no
-divergence, fully VPU-vectorized.  n must be a power of two (pad with +inf).
+compare-exchange stages — no gathers, no divergence, fully VPU-vectorized.
+n must be a power of two (pad with +inf).
 
 Stages: for k in 2,4,..,n (merge size), for j in k/2,..,1 (distance):
 elements at distance j swap so each k-block becomes ascending/descending by
-position — log^2(n) dense passes over the tile.
+position — log^2(n) dense passes over the tile.  Each pass fetches every
+element's partner (index i XOR j) with two lane rolls, by +j and -j, and a
+lane-iota mask, so rows stay 2-D (Mosaic refuses the 4-D pair reshape); the
+stage loop is a ``fori_loop`` over dynamic roll distances, which keeps the
+compiled kernel one stage long.
 
 Rows sort independently, so the launch *grids over row blocks*: each grid
-step sorts ``block_rows`` rows in one VMEM tile of <= _ROW_BLOCK_ELEMS
-elements.  A (T, tile_n) call — the multi-tile radix shuffle's T local
-sorts (repro.core.kshuffle) — is therefore ONE pallas_call at any T; only
-a single row's padded width is bounded by VMEM.
+step sorts ``block_rows`` rows (a multiple of 8 sublanes) in one VMEM tile
+of <= _ROW_BLOCK_ELEMS elements, rows padded to whole 128-lane vregs.  A
+(T, tile_n) call — the multi-tile radix shuffle's T local sorts
+(repro.core.kshuffle) — is therefore ONE pallas_call at any T; only a
+single 8-row block's padded width is bounded by VMEM.
 """
 from __future__ import annotations
 
@@ -23,45 +28,49 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-
-def _compare_exchange(keys, vals, k: int, j: int):
-    """One bitonic stage on (rows, n): partners at distance j within 2j-blocks,
-    direction flips every k elements."""
-    rows, n = keys.shape
-    kb = keys.reshape(rows, n // (2 * j), 2, j)
-    vb = vals.reshape(rows, n // (2 * j), 2, j)
-    a_k, b_k = kb[:, :, 0, :], kb[:, :, 1, :]
-    a_v, b_v = vb[:, :, 0, :], vb[:, :, 1, :]
-    # ascending iff floor(global_index / k) is even
-    base = jnp.arange(n // (2 * j)) * (2 * j)
-    ascending = ((base // k) % 2 == 0)[None, :, None]
-    swap = jnp.where(ascending, a_k > b_k, a_k < b_k)
-    new_a_k = jnp.where(swap, b_k, a_k)
-    new_b_k = jnp.where(swap, a_k, b_k)
-    new_a_v = jnp.where(swap, b_v, a_v)
-    new_b_v = jnp.where(swap, a_v, b_v)
-    keys = jnp.stack([new_a_k, new_b_k], axis=2).reshape(rows, n)
-    vals = jnp.stack([new_a_v, new_b_v], axis=2).reshape(rows, n)
-    return keys, vals
+_SUBLANES = 8
+_LANES = 128
 
 
 def _bitonic_kernel(k_ref, v_ref, ok_ref, ov_ref):
     keys, vals = k_ref[...], v_ref[...]
     n = keys.shape[-1]
-    k = 2
-    while k <= n:                      # static Python loop: n is a trace const
-        j = k // 2
-        while j >= 1:
-            keys, vals = _compare_exchange(keys, vals, k, j)
-            j //= 2
-        k *= 2
+    lane = jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)
+
+    def merge(log_k, kv):
+        k = jnp.left_shift(jnp.int32(1), log_k)
+        ascending = (lane & k) == 0        # floor(index / k) is even
+
+        def compare_exchange(step, kv):
+            keys, vals = kv
+            j = jnp.right_shift(k, step + 1)
+            upper = (lane & j) != 0        # partner is index - j, else + j
+
+            def partner(x):
+                return jnp.where(upper, pltpu.roll(x, j, 1),
+                                 pltpu.roll(x, n - j, 1))
+
+            pk, pv = partner(keys), partner(vals)
+            lo = jnp.where(upper, pk, keys)
+            hi = jnp.where(upper, keys, pk)
+            swap = (ascending & (lo > hi)) | (~ascending & (lo < hi))
+            return jnp.where(swap, pk, keys), jnp.where(swap, pv, vals)
+
+        return jax.lax.fori_loop(0, log_k, compare_exchange, kv)
+
+    keys, vals = jax.lax.fori_loop(1, n.bit_length(), merge, (keys, vals))
     ok_ref[...] = keys
     ov_ref[...] = vals
 
 
 #: per-grid-step VMEM budget (elements per array) — one row block
-_ROW_BLOCK_ELEMS = 1 << 18
+_ROW_BLOCK_ELEMS = 1 << 16
+
+
+#: widest row whose 8-row block fits ``_ROW_BLOCK_ELEMS``
+MAX_ROW_WIDTH = _ROW_BLOCK_ELEMS // _SUBLANES
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -69,45 +78,45 @@ def bitonic_sort(keys: jnp.ndarray, values: jnp.ndarray, *,
                  interpret: bool = False):
     """Sort each row of (rows, n) ascending by key, permuting values along.
 
-    n is padded to the next power of two with +inf keys (dropped on return).
-    Rows are independent networks, so the launch grids over blocks of
-    ``_ROW_BLOCK_ELEMS // n_pad`` rows — any row count fits; only a single
-    row's padded width must fit one VMEM tile (n_pad <= _ROW_BLOCK_ELEMS).
+    n is padded to the next power of two, and to at least one 128-lane
+    vreg, with max-value keys (dropped on return).  Rows are independent
+    networks, so the launch grids over blocks of a multiple of 8 rows within
+    ``_ROW_BLOCK_ELEMS`` — any row count fits; only 8 rows of the padded
+    width must fit one VMEM tile (8 * n_pad <= _ROW_BLOCK_ELEMS).
     """
     if keys.shape != values.shape or keys.ndim != 2:
         raise ValueError("bitonic_sort expects matching (rows, n) arrays")
     rows, n = keys.shape
     if n == 0 or rows == 0:          # empty rows are trivially sorted
         return keys, values
-    n_pad = 1
+    n_pad = _LANES
     while n_pad < n:
         n_pad *= 2
-    if n_pad > _ROW_BLOCK_ELEMS:
+    if n_pad > MAX_ROW_WIDTH:
         raise ValueError(
             f"bitonic_sort: one row of n={n} (padded {n_pad}) exceeds the "
-            f"single-VMEM-tile budget ({_ROW_BLOCK_ELEMS}); split the row "
-            f"into tiles first (see repro.core.kshuffle)")
-    if n_pad != n:
-        big = (jnp.finfo(keys.dtype).max
-               if jnp.issubdtype(keys.dtype, jnp.floating)
-               else jnp.iinfo(keys.dtype).max)
-        keys = jnp.pad(keys, ((0, 0), (0, n_pad - n)), constant_values=big)
-        values = jnp.pad(values, ((0, 0), (0, n_pad - n)))
-    block_rows = min(rows, max(1, _ROW_BLOCK_ELEMS // n_pad))
+            f"single-VMEM-tile budget ({_ROW_BLOCK_ELEMS} elements per "
+            f"{_SUBLANES}-row block); split the row into tiles first (see "
+            f"repro.core.kshuffle)")
+    big = (jnp.finfo(keys.dtype).max
+           if jnp.issubdtype(keys.dtype, jnp.floating)
+           else jnp.iinfo(keys.dtype).max)
+    rows_8 = -(-rows // _SUBLANES) * _SUBLANES
+    block_rows = min(rows_8, _ROW_BLOCK_ELEMS // n_pad
+                     // _SUBLANES * _SUBLANES)
     grid_r = -(-rows // block_rows)
-    if grid_r * block_rows != rows:  # zero rows sort (harmlessly) in-block
-        pad_r = grid_r * block_rows - rows
-        keys = jnp.pad(keys, ((0, pad_r), (0, 0)))
-        values = jnp.pad(values, ((0, pad_r), (0, 0)))
+    # Padded rows sort (harmlessly) in-block; padded lanes sort last.
+    pad = ((0, grid_r * block_rows - rows), (0, n_pad - n))
+    keys = jnp.pad(keys, pad, constant_values=big)
+    values = jnp.pad(values, pad)
     spec = pl.BlockSpec((block_rows, n_pad), lambda i: (i, 0))
     out_k, out_v = pl.pallas_call(
         _bitonic_kernel,
         grid=(grid_r,),
         in_specs=[spec, spec],
         out_specs=[spec, spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((grid_r * block_rows, n_pad), keys.dtype),
-            jax.ShapeDtypeStruct((grid_r * block_rows, n_pad), values.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(keys.shape, keys.dtype),
+                   jax.ShapeDtypeStruct(values.shape, values.dtype)],
         interpret=interpret,
     )(keys, values)
     return out_k[:rows, :n], out_v[:rows, :n]

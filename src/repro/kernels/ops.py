@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On the CPU container the kernels execute in interpret mode (the kernel body
+On the CPU backend the kernels execute in interpret mode (the kernel body
 runs as traced jnp — bit-identical control flow to the TPU lowering); on a
-TPU backend they compile to Mosaic.  The wrappers also do the shape hygiene
+TPU backend they compile to Mosaic; any other backend is an error.  The wrappers also do the shape hygiene
 the kernels assume: GQA head broadcasting, head-dim padding to the 128-lane
 MXU width, power-of-two padding for the bitonic network.
 """
@@ -21,7 +21,14 @@ from . import ssm_scan as _ssm
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compile with Mosaic on a TPU; interpret only on the CPU backend.  Any
+    other backend has no Pallas TPU lowering, and interpreting there would
+    hide that the kernels never ran on the device."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas TPU kernels cannot run on the {backend!r} backend")
+    return backend == "cpu"
 
 
 def prefix_scan(x: jnp.ndarray, *, exclusive: bool = False,

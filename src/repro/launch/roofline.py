@@ -28,10 +28,10 @@ import json
 import pathlib
 from typing import Any, Dict, Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
-HBM_GB = 16            # v5e; kimi-class memory exceptions noted inline
+from ..core.costmodel import device_peaks
+
+#: the dry-run cells are compiled for v5e meshes
+PEAKS = device_peaks("TPU v5 lite")
 
 RESULTS_DIR = pathlib.Path(__file__).resolve().parents[3] / "experiments"
 COLLECTIVE_OPS = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
@@ -96,9 +96,9 @@ def analyze_cell(arch: str, shape: str, mesh: str,
     # MODEL_FLOPS (cells with *inner* scans — grad-accum microbatching,
     # chunked lax.map — still count those bodies once; the analytic floor
     # is then the honest estimate).
-    compute_t = max(corr["flops"], model_flops) / PEAK_FLOPS
-    memory_t = corr["bytes"] / HBM_BW
-    coll_t = corr["coll"] / ICI_BW
+    compute_t = max(corr["flops"], model_flops) / PEAKS.flops_bf16
+    memory_t = corr["bytes"] / PEAKS.hbm_bw
+    coll_t = corr["coll"] / PEAKS.ici_bw_per_link
     dom = max(("compute", compute_t), ("memory", memory_t),
               ("collective", coll_t), key=lambda kv: kv[1])
     mem = rec.get("memory", {})
@@ -116,7 +116,8 @@ def analyze_cell(arch: str, shape: str, mesh: str,
         "model_flops_per_chip": model_flops,
         "useful_ratio": model_flops / corr["flops"] if corr["flops"] else 0,
         "per_device_gb": per_dev_gb,
-        "fits_16gb": per_dev_gb is not None and per_dev_gb <= HBM_GB,
+        "fits_16gb": (per_dev_gb is not None
+                      and per_dev_gb * 1e9 <= PEAKS.hbm_bytes),
     }
 
 

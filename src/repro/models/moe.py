@@ -163,7 +163,7 @@ def _moe_shuffle(p: Params, cfg: ArchConfig, x: jnp.ndarray) -> MoEOut:
     if mesh is None or "model" not in mesh.axis_names:
         return _moe_einsum(p, cfg, x)
     from jax.sharding import PartitionSpec as P
-    from ..core.distributed import shard_map, shuffle_alltoall
+    from ..core.distributed import shuffle_alltoall
 
     b, s, d = x.shape
     e, k = cfg.n_experts, cfg.top_k
@@ -236,7 +236,7 @@ def _moe_shuffle(p: Params, cfg: ArchConfig, x: jnp.ndarray) -> MoEOut:
         return y_tok.reshape(b_l, s, d), drop
 
     bspec = P(batch_axes, None, None)
-    y, dropped = shard_map(
+    y, dropped = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(bspec, bspec, bspec,
                   P("model", "data", None), P("model", "data", None),

@@ -26,7 +26,7 @@ that every layer grown since PR 1 reports into —
 **Zero overhead on jitted paths** is a hard contract: instrumentation lives
 at host boundaries only, the default hook is the no-op :data:`NULL_TRACER`,
 and a live :class:`Tracer` silently drops :meth:`Tracer.event` calls made
-while jax is tracing (``jax.core.trace_state_clean()`` is False), so a
+while jax is tracing (:func:`not_tracing` is False), so a
 jitted round program lowers to exactly the same HLO with or without a
 tracer attached — outputs and :class:`~repro.core.costmodel.CostAccum`
 stay bit-identical (``tests/test_obs.py``).  The one deliberate exception
@@ -62,9 +62,9 @@ __all__ = ["TraceEvent", "Tracer", "NullTracer", "NULL_TRACER",
 _CONTEXT_KEYS = ("plan", "stage", "digest")
 
 
-def _trace_clean() -> bool:
+def not_tracing() -> bool:
     """True when jax is NOT currently tracing (host/eager execution)."""
-    return jax.core.trace_state_clean()
+    return jax.core.trace_ctx.is_top_level()
 
 
 class _AbstractValue(Exception):
@@ -143,7 +143,7 @@ class _Span:
     def __enter__(self) -> "_Span":
         # A span opened at jax trace time must not record (nor leak stack
         # frames a later eager event would inherit stale context from).
-        self._live = _trace_clean()
+        self._live = not_tracing()
         if self._live:
             self._tracer._stack.append(self.attrs)
             self._t0 = self._tracer.clock()
@@ -220,7 +220,7 @@ class Tracer:
               **attrs) -> None:
         """Record an instant event (``_dur`` attaches a measured duration).
         No-op while jax is tracing — jitted paths stay untouched."""
-        if not _trace_clean():
+        if not not_tracing():
             self.skipped += 1
             return
         self._record(kind, dur=_dur, attrs=attrs)
@@ -238,12 +238,12 @@ class Tracer:
     def count(self, name: str, n: int = 1) -> None:
         """Increment a metrics counter — gated like :meth:`event`, so
         jitted paths never count at trace time."""
-        if _trace_clean():
+        if not_tracing():
             self.metrics.counter(name).inc(n)
 
     def observe(self, name: str, value: float) -> None:
         """Record a histogram observation (gated like :meth:`event`)."""
-        if _trace_clean():
+        if not_tracing():
             self.metrics.histogram(name).observe(value)
 
     def _record(self, kind: str, dur: Optional[float],

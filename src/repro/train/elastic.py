@@ -44,6 +44,7 @@ def plan_mesh(n_devices: Optional[int] = None,
         mp -= 1
     dp = n // mp
     return jax.make_mesh((dp, mp), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2,
                          devices=devs[:dp * mp])
 
 

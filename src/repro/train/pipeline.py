@@ -89,8 +89,7 @@ def run_pipeline(stage_fn: Callable, stacked_params: Any,
         local = jax.tree_util.tree_map(lambda x: x[0], params)  # this stage
         return body(local, mb)
 
-    from ..core.distributed import shard_map
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         wrapper, mesh=mesh,
         in_specs=(pspec, P()), out_specs=P(),
         check_vma=False))(stacked_params, microbatches)

@@ -184,4 +184,5 @@ class Trainer:
 
 
 def _dummy_mesh():
-    return jax.make_mesh((1,), ("data",))
+    return jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))

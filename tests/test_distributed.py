@@ -29,9 +29,10 @@ def test_shuffle_alltoall_roundtrip():
     order within (sender, receiver) pairs, drops counted."""
     out = run_with_devices("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_host_mesh
     from jax.sharding import PartitionSpec as P
-    from repro.core.distributed import shard_map, shuffle_alltoall
-    mesh = jax.make_mesh((8,), ("x",))
+    from repro.core.distributed import shuffle_alltoall
+    mesh = make_host_mesh((8,), ("x",))
     n_local = 16
     def body(dests, vals):
         out = shuffle_alltoall(dests, vals, "x", capacity=n_local)
@@ -39,7 +40,7 @@ def test_shuffle_alltoall_roundtrip():
     rng = np.random.default_rng(0)
     dests = jnp.asarray(rng.integers(0, 8, (8, n_local)).astype(np.int32))
     vals = jnp.arange(8 * n_local, dtype=jnp.float32).reshape(8, n_local)
-    f = jax.jit(shard_map(body, mesh=mesh,
+    f = jax.jit(jax.shard_map(body, mesh=mesh,
                 in_specs=(P("x", None), P("x", None)),
                 out_specs=(P("x", None), P("x", None), P("x"))))
     payload, valid, dropped = f(dests, vals)
@@ -62,7 +63,7 @@ def test_shuffle_alltoall_roundtrip():
 
 def test_sharded_engine_kernel_scatter_multishard():
     """ShardedEngine(shuffle_impl='kernel') at axis size 8: the Pallas
-    per-shard scatter — the path check_rep=False un-gates inside shard_map —
+    per-shard scatter — the path check_vma=False un-gates inside shard_map —
     must stay bit-identical to the dense sharded and local engines
     (mailbox, validity, and every stat) under real cross-shard collectives."""
     out = run_with_devices("""
@@ -102,18 +103,19 @@ def test_sharded_engine_kernel_scatter_multishard():
 def test_funnel_allreduce_matches_psum():
     out = run_with_devices("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_host_mesh
     from jax.sharding import PartitionSpec as P
-    from repro.core.distributed import funnel_allreduce, shard_map
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    from repro.core.distributed import funnel_allreduce
+    mesh = make_host_mesh((2, 4), ("pod", "data"))
     x = jnp.arange(2 * 4 * 16, dtype=jnp.float32).reshape(8, 16)
     def fun(x):
         return funnel_allreduce(x, "data", "pod", scatter_dim=0)
     def ref(x):
         return jax.lax.psum(jax.lax.psum(x, "data"), "pod")
     spec = P(("pod", "data"), None)
-    f1 = jax.jit(shard_map(fun, mesh=mesh, in_specs=(spec,),
+    f1 = jax.jit(jax.shard_map(fun, mesh=mesh, in_specs=(spec,),
                                out_specs=spec))
-    f2 = jax.jit(shard_map(ref, mesh=mesh, in_specs=(spec,),
+    f2 = jax.jit(jax.shard_map(ref, mesh=mesh, in_specs=(spec,),
                                out_specs=spec))
     np.testing.assert_allclose(np.asarray(f1(x)), np.asarray(f2(x)),
                                rtol=1e-6)
@@ -127,9 +129,10 @@ def test_softmax_merge_flash_decode():
     the (max, sum-exp) funnel."""
     out = run_with_devices("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_host_mesh
     from jax.sharding import PartitionSpec as P
-    from repro.core.distributed import AttnPartial, shard_map, softmax_merge_axis
-    mesh = jax.make_mesh((8,), ("kv",))
+    from repro.core.distributed import AttnPartial, softmax_merge_axis
+    mesh = make_host_mesh((8,), ("kv",))
     rng = np.random.default_rng(0)
     T, D = 64, 16
     q = jnp.asarray(rng.normal(size=(D,)).astype(np.float32))
@@ -141,7 +144,7 @@ def test_softmax_merge_flash_decode():
         p = jnp.exp(s - m)
         return softmax_merge_axis(
             AttnPartial(m=m, l=jnp.sum(p), o=p @ v_shard), "kv")
-    f = jax.jit(shard_map(local, mesh=mesh,
+    f = jax.jit(jax.shard_map(local, mesh=mesh,
                 in_specs=(P("kv", None), P("kv", None)), out_specs=P(None)))
     got = f(k, v)
     w = jax.nn.softmax(k @ q)
@@ -155,15 +158,16 @@ def test_softmax_merge_flash_decode():
 def test_sharded_sample_sort():
     out = run_with_devices("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_host_mesh
     from jax.sharding import PartitionSpec as P
-    from repro.core.distributed import shard_map, sharded_sample_sort
-    mesh = jax.make_mesh((8,), ("x",))
+    from repro.core.distributed import sharded_sample_sort
+    mesh = make_host_mesh((8,), ("x",))
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(8 * 64,)).astype(np.float32))
     def body(xs):
         o = sharded_sample_sort(xs, "x")
         return o.values, o.valid, o.dropped[None]
-    f = jax.jit(shard_map(body, mesh=mesh,
+    f = jax.jit(jax.shard_map(body, mesh=mesh,
         in_specs=(P("x"),), out_specs=(P("x"), P("x"), P("x"))))
     out_values, out_valid, out_dropped = f(x)
     class O: pass
@@ -183,10 +187,11 @@ def test_moe_shuffle_matches_einsum():
     (up to capacity-drop differences, tested with ample capacity)."""
     out = run_with_devices("""
     import jax, jax.numpy as jnp, numpy as np, dataclasses
+    from repro.launch.mesh import make_host_mesh
     from repro.configs import get_config
     from repro.models import sharding as shmod
     from repro.models.moe import init_moe, apply_moe
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_host_mesh((2, 4), ("data", "model"))
     cfg = get_config("kimi-k2-1t-a32b", reduced=True)
     cfg = dataclasses.replace(cfg, capacity_factor=8.0, shared_expert=False)
     p = init_moe(jax.random.PRNGKey(0), cfg)
@@ -208,9 +213,10 @@ def test_compressed_pod_training_close_to_exact():
     of the exact pipeline on the same data."""
     out = run_with_devices("""
     import jax, numpy as np
+    from repro.launch.mesh import make_host_mesh
     from repro.configs import get_config
     from repro.train import Trainer, TrainConfig
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_host_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_config("qwen1.5-0.5b", reduced=True)
     mk = lambda mode: TrainConfig(arch=cfg, global_batch=8, seq_len=32,
                                   steps=10, log_every=1, warmup_steps=2,
@@ -228,6 +234,7 @@ def test_elastic_restart_across_mesh_sizes():
     """Checkpoint on one mesh, resume on a different one (elastic)."""
     out = run_with_devices("""
     import tempfile, jax, numpy as np
+    from repro.launch.mesh import make_host_mesh
     from repro.configs import get_config
     from repro.train import Trainer, TrainConfig
     from repro.train.elastic import plan_mesh
@@ -236,11 +243,11 @@ def test_elastic_restart_across_mesh_sizes():
     mk = lambda: TrainConfig(arch=cfg, global_batch=8, seq_len=16, steps=6,
                              ckpt_dir=d, ckpt_every=3, log_every=1,
                              warmup_steps=2, seed=1)
-    mesh1 = jax.make_mesh((1, 8, 1), ("pod", "data", "model"))
+    mesh1 = make_host_mesh((1, 8, 1), ("pod", "data", "model"))
     t1 = Trainer(mk(), mesh=mesh1)
     t1.train(steps=3)
     # "lose" half the fleet: resume on 4 devices
-    mesh2 = jax.make_mesh((1, 2, 2), ("pod", "data", "model"))
+    mesh2 = make_host_mesh((1, 2, 2), ("pod", "data", "model"))
     t2 = Trainer(mk(), mesh=mesh2)
     assert t2.maybe_resume() and t2.step == 3
     r2 = t2.train()
@@ -257,8 +264,9 @@ def test_pipeline_parallel_matches_sequential():
     grads flow through the pipelined graph."""
     out = run_with_devices("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_host_mesh
     from repro.train.pipeline import run_pipeline
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = make_host_mesh((4,), ("pod",))
     rng = np.random.default_rng(0)
     n_stages, n_micro, mb, d = 4, 6, 8, 16
     ws = jnp.asarray(rng.normal(size=(n_stages, d, d)).astype(np.float32)) * 0.3

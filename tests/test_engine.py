@@ -183,8 +183,9 @@ class TestAlgorithmParity:
     def test_multisearch_capacity_drop_reporting(self):
         """With a tight capacity the w.h.p. overflow event is *reported*
         (identically on each backend), not a crash."""
-        q = jnp.asarray(RNG.normal(size=64).astype(np.float32))
-        piv = jnp.sort(jnp.asarray(RNG.normal(size=10).astype(np.float32)))
+        rng = np.random.default_rng(7)
+        q = jnp.asarray(rng.normal(size=64).astype(np.float32))
+        piv = jnp.sort(jnp.asarray(rng.normal(size=10).astype(np.float32)))
         drops = [int(multisearch_mr(q, piv, 4, engine=e,
                                     capacity=2).stats.dropped)
                  for e in engines()]
@@ -209,3 +210,45 @@ class TestAlgorithmParity:
         assert isinstance(get_engine("local"), LocalEngine)
         with pytest.raises(ValueError):
             get_engine("bogus")
+
+
+def test_quantile_splitters_past_int32_index_product():
+    """The splitter positions (i * s) // V stay exact when (V - 1) * s
+    passes int32 — 2^14 buckets x 32 samples each, a chip-sized sort."""
+    from repro.core.sortmr import quantile_splitters
+    n, V, oversample = 1 << 20, 1 << 14, 32
+    x = np.random.default_rng(5).standard_normal(n, dtype=np.float32)
+    key = jax.random.PRNGKey(5)
+    splitters, s = quantile_splitters(jnp.asarray(x), V, oversample, key)
+    assert s == V * oversample and (V - 1) * s > 2 ** 31
+    sample = np.sort(x[np.asarray(jax.random.permutation(key, n))[:s]])
+    want = sample[np.arange(1, V, dtype=np.int64) * s // V]
+    np.testing.assert_array_equal(np.asarray(splitters), want)
+
+
+@pytest.mark.parametrize("V", [2, 1 << 12, 1 << 14, 1 << 20])
+def test_default_oversample_bounds_overflow(V):
+    """The default samples per bucket hold the union-bounded Chernoff tail
+    of any bucket passing ``slack`` times its share below 1e-9."""
+    import math
+    from repro.core.sortmr import default_oversample
+    for slack in (1.5, 3.0, 8.0):
+        k = default_oversample(V, slack)
+        assert k >= 8
+        if V > 1:
+            assert V * math.exp(-k * (slack - 1 - math.log(slack))) <= 1e-9
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_sort_plan_defaults_do_not_drop_at_many_buckets(seed):
+    """2^14 buckets: 8 samples per bucket (the old default) overflowed
+    these seeds' buckets and dropped items; the default must not."""
+    from repro.core import sort_plan
+    n, M = 1 << 20, 64
+    plan = sort_plan(n, M, levels=2)
+    assert plan.n_nodes == 1 << 14
+    x = np.random.default_rng(0).standard_normal(n, dtype=np.float32)
+    res = LocalEngine().compile(plan)(jnp.asarray(x),
+                                      key=jax.random.PRNGKey(seed))
+    assert int(res.stats.dropped) == 0
+    np.testing.assert_array_equal(np.asarray(res.values), np.sort(x))

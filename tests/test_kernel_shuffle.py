@@ -105,9 +105,10 @@ class TestGuardBoundaries:
     """kernel_fits pinned at the exact guard edges (DESIGN.md §7).
 
     The old cliffs — single-VMEM-tile n <= 2^18 and the global int32 key
-    space — are gone; the two remaining guards (minimum derived tile width,
-    count-matrix budget) are asserted on both sides of each boundary.  Pure
-    predicate checks: nothing here executes a kernel at the big shapes.
+    space — are gone; the remaining guards (bucket count and sort row width
+    within VMEM, count-matrix budget) are asserted on both sides of each
+    boundary.  Pure predicate checks: nothing here executes a kernel at the
+    big shapes.
     """
 
     def test_old_single_tile_cliff_gone(self):
@@ -117,9 +118,9 @@ class TestGuardBoundaries:
         assert kernel_fits(_MAX_SORT_N + 1, 64)
 
     def test_old_int32_key_cliff_gone(self):
-        # Old global key dest*n_pad+src: 65537 * pow2ceil(40000) > 2^31.
-        # Segmented per-tile keys stay at 65537 * 128 — comfortably int32.
-        assert kernel_fits(40000, 2 ** 16)
+        # Old global key dest*n_pad+src: 8192 * pow2ceil(300000) > 2^31.
+        # Segmented per-tile keys stay at 8192 * 4096 — comfortably int32.
+        assert kernel_fits(300000, 8191)
 
     def test_counts_budget_exact_edge(self):
         # V+1 = 1024 -> derived tile 4096 -> T <= 2^25/1024 = 32768 tiles,
@@ -128,30 +129,34 @@ class TestGuardBoundaries:
         assert not kernel_fits((1 << 27) + 1, 1023)
 
     def test_min_tile_width_exact_edge(self):
-        # tile = pow2floor(2^24 // (V+1)): V+1 = 2^21 -> tile 8 (= _MIN_TILE_N
-        # fits); V+1 = 2^21 + 1 -> tile 4 -> bail dense.
-        assert kernel_fits(100, (1 << 21) - 1)
-        assert not kernel_fits(100, 1 << 21)
+        # The tile no longer shrinks with V; the node count itself is bounded
+        # by the one-hot VMEM budget of bincount_tiles -> past it, bail dense.
+        from repro.kernels.bincount import MAX_BUCKETS
+        assert kernel_fits(100, MAX_BUCKETS)
+        assert not kernel_fits(100, MAX_BUCKETS + 1)
 
     def test_explicit_tile_int32_edge(self):
-        # An explicit tile_n must keep (V+1)*tile_n within int32: with
-        # V+1 = 2^21, tile 512 is the last fitting power of two (2^30).
-        assert kernel_fits(512, (1 << 21) - 1, tile_n=512)
-        assert not kernel_fits(512, (1 << 21) - 1, tile_n=1024)
+        # An explicit tile_n must fit one bitonic row block in VMEM, which
+        # (with the bucket bound) keeps (V+1)*tile_n within int32.
+        from repro.kernels.bitonic_sort import MAX_ROW_WIDTH
+        w = MAX_ROW_WIDTH
+        assert kernel_fits(512, (1 << 15) - 1, tile_n=w)
+        assert (1 << 15) * w < 2 ** 31 - 1
+        assert not kernel_fits(512, (1 << 15) - 1, tile_n=w + 1)
 
     def test_empty_input_fits_iff_tile_does(self):
         assert kernel_fits(0, 5)
         assert not kernel_fits(0, 1 << 22)
 
     def test_strict_guard_raises_key_space(self):
-        with pytest.raises(ValueError, match="key space"):
+        with pytest.raises(ValueError, match="one-hot VMEM budget"):
             kernel_shuffle(jnp.zeros((8,), jnp.int32),
                            jnp.zeros((8,), jnp.float32), 1 << 22, 4)
 
     def test_strict_guard_raises_counts_budget(self):
         with pytest.raises(ValueError, match="counts budget"):
-            kernel_shuffle(jnp.zeros((200,), jnp.int32),
-                           jnp.zeros((200,), jnp.float32), (1 << 21) - 1, 4,
+            kernel_shuffle(jnp.zeros((40000,), jnp.int32),
+                           jnp.zeros((40000,), jnp.float32), 8191, 4,
                            tile_n=8)
 
     def test_strict_guard_is_the_predicate(self):
@@ -163,7 +168,9 @@ class TestGuardBoundaries:
                  (1 << 27, 1023, None), ((1 << 27) + 1, 1023, None),
                  (100, (1 << 21) - 1, None), (100, 1 << 21, None),
                  (512, (1 << 21) - 1, 512), (512, (1 << 21) - 1, 1024),
-                 (200, (1 << 21) - 1, 8)]
+                 (200, (1 << 21) - 1, 8), (100, 1 << 15, None),
+                 (100, (1 << 15) + 1, None), (512, 5, 8192),
+                 (512, 5, 8193), (40000, 8191, 8)]
         for n, V, t in cases:
             raised = False
             try:
@@ -258,7 +265,7 @@ class TestShardedPerLevelRouting:
         """
         from repro.core import kshuffle as K
         V, cap = 8, 4
-        tile = K._tile_width(V)                  # derived width (4096)
+        tile = K._TILE_N                         # default width (4096)
         # Budget admits exactly one tile of counts: n <= tile fits,
         # n > tile does not.
         monkeypatch.setattr(K, "_COUNTS_BUDGET", V + 1)
@@ -280,7 +287,7 @@ class TestShardedPerLevelRouting:
         returns to the kernel below it, bit-identically, same instance."""
         from repro.core import kshuffle as K
         V, cap = 8, 4
-        tile = K._tile_width(V)
+        tile = K._TILE_N
         monkeypatch.setattr(K, "_COUNTS_BUDGET", V + 1)
         rng = np.random.default_rng(10)
         eng = get_engine("pallas")
@@ -332,7 +339,7 @@ class TestEngineWiring:
 
     def test_sharded_kernel_scatter_parity(self):
         """ShardedEngine(shuffle_impl='kernel'): the per-shard local scatter
-        runs the Pallas path inside shard_map (check_rep relaxed)."""
+        runs the Pallas path inside shard_map (check_vma relaxed)."""
         rng = np.random.default_rng(11)
         V, cap = 8, 3
         dests = jnp.asarray(rng.integers(-1, V, 40).astype(np.int32))

@@ -97,8 +97,8 @@ def test_bitonic_sort_grids_over_row_blocks(monkeypatch):
     """Row counts past one VMEM block split across grid steps (the T-tile
     sort of the radix shuffle): shrink the budget so a small case grids,
     including a non-multiple tail row block."""
-    monkeypatch.setattr(bitonic_mod, "_ROW_BLOCK_ELEMS", 64)
-    rows, n = 10, 12                  # n_pad 16 -> block_rows 4 -> grid 3
+    monkeypatch.setattr(bitonic_mod, "_ROW_BLOCK_ELEMS", 8 * 128)
+    rows, n = 20, 100                 # n_pad 128 -> block_rows 8 -> grid 3
     base = RNG.permutation(rows * n * 4)[:rows * n].reshape(rows, n)
     k = jnp.asarray(base.astype(np.int32))
     v = jnp.asarray(RNG.normal(size=(rows, n)).astype(np.float32))
@@ -109,7 +109,7 @@ def test_bitonic_sort_grids_over_row_blocks(monkeypatch):
 
 
 def test_bitonic_sort_single_row_width_guard(monkeypatch):
-    monkeypatch.setattr(bitonic_mod, "_ROW_BLOCK_ELEMS", 8)
+    monkeypatch.setattr(bitonic_mod, "MAX_ROW_WIDTH", 64)
     with pytest.raises(ValueError, match="single-VMEM-tile"):
         raw_bitonic_sort(jnp.zeros((1, 9), jnp.int32),
                          jnp.zeros((1, 9), jnp.float32), interpret=True)

@@ -248,7 +248,7 @@ class TestKernelGuardFallback:
         from repro.core import kshuffle as K
         V = 8
         monkeypatch.setattr(K, "_COUNTS_BUDGET", V + 1)  # one tile of counts
-        n = 2 * K._tile_width(V)                         # two tiles: too big
+        n = 2 * K._TILE_N                                # two tiles: too big
         assert not K.kernel_fits(n, V)
         eng = get_engine("pallas")
         dests = jnp.asarray(RNG.integers(0, V, n).astype(np.int32))
@@ -269,7 +269,7 @@ class TestKernelGuardFallback:
         assert kernel_fits(100, 8)
         # the old single-tile and int32-key cliffs are gone...
         assert kernel_fits((1 << 18) + 1, 4)
-        assert kernel_fits(40000, 2 ** 16)
-        # ...what remains: tile width floor and the counts budget
+        assert kernel_fits(300000, 8191)
+        # ...what remains: node count within VMEM and the counts budget
         assert not kernel_fits(100, 1 << 21)
-        assert not kernel_fits(70000, 2 ** 16)
+        assert not kernel_fits((1 << 27) + 1, 1023)
