@@ -195,12 +195,12 @@ def bench_shuffle(quick):
         payload = jnp.asarray(rng.normal(size=n).astype(np.float32))
         d_fn = jax.jit(lambda d, p, V=V, cap=cap: deng.shuffle(d, p, V, cap))
         k_fn = jax.jit(lambda d, p, V=V, cap=cap: keng.shuffle(d, p, V, cap))
-        K.route_log.reset()
+        keng.route_log.reset()
         box_k, st_k = jax.block_until_ready(k_fn(dests, payload))
-        routed = K.route_log.snapshot() == (1, 0)
+        routed = keng.route_log.snapshot() == (1, 0)
         assert routed, \
             f"bench_shuffle: kernel path not taken at N{n}_V{V} " \
-            f"(route_log={K.route_log.snapshot()})"
+            f"(route_log={keng.route_log.snapshot()})"
         kernel_routes += 1
         box_d, st_d = jax.block_until_ready(d_fn(dests, payload))
         parity = bool(jnp.array_equal(box_d.valid, box_k.valid)
@@ -427,7 +427,6 @@ def bench_shape(quick):
     """
     import json
     from repro.core import LocalEngine, get_engine, hull2d_plan, prefix_plan
-    from repro.core import kshuffle as K
     from repro.core.funnel import funnel_write_plan
     from repro.core.plan import execute_plan
 
@@ -457,10 +456,10 @@ def bench_shape(quick):
         # Kernel column: the shaped plan on the pallas engine.  Every
         # per-stage routing decision (made while the first call traces)
         # must take the kernel, and the result must match the dense column.
-        K.route_log.reset()
+        kengine.route_log.reset()
         _, call_k = make_plan_call(True, kengine)
         res_k = jax.block_until_ready(call_k())
-        routed = K.route_log.snapshot()
+        routed = kengine.route_log.snapshot()
         assert routed[0] > 0 and routed[1] == 0, \
             f"bench_shape: {label} fell back to dense on the kernel " \
             f"engine (route_log={routed})"
@@ -871,163 +870,11 @@ def bench_obs(quick):
     print("obs_bench_json,0,wrote BENCH_obs.json (1 row)")
 
 
-_SCALING_CHILD = r"""
-import json
-import time
-
-import numpy as np
-import jax
-import jax.numpy as jnp
-
-from repro.core import CostAccum, ShardedEngine, hull2d_plan, sort_plan
-from repro.obs import Tracer, summarize
-
-DEV = jax.device_count()
-rng = np.random.default_rng(0)
-eng_o = ShardedEngine(tracer=Tracer())                 # double-buffered
-eng_s = ShardedEngine(overlap=False, tracer=Tracer())  # sequential comparator
-
-
-def tree_equal(a, b):
-    la = jax.tree_util.tree_leaves(a)
-    lb = jax.tree_util.tree_leaves(b)
-    return len(la) == len(lb) and all(
-        np.array_equal(np.asarray(x), np.asarray(y)) for x, y in zip(la, lb))
-
-
-# --- microbench: one R-round double-buffered window (ring rotation) -------
-R, cap = 16, 4
-V = eng_o.aligned_nodes(32 * DEV)                      # weak scaling in V
-entry = jnp.asarray(rng.integers(0, V, V * cap).astype(np.int32))
-payload = jnp.asarray(rng.normal(size=V * cap).astype(np.float32))
-node = jnp.arange(V, dtype=jnp.int32)[:, None]
-
-
-def fn(r, ids, box):
-    return jnp.where(box.valid, (node + 1 + r) % V, -1), box.payload
-
-
-def run(eng, early):
-    box, st = eng.shuffle(entry, payload, V, cap)
-    acc = CostAccum.zero().add_round_stats(st)
-    jax.block_until_ready(box.valid)
-    t0 = time.perf_counter()
-    box, acc = eng.run_rounds(fn, box, R, accum=acc, early_dests=early)
-    jax.block_until_ready(box.valid)
-    return box, acc, time.perf_counter() - t0
-
-
-run(eng_s, False), run(eng_o, True)                    # compile warmup
-box_s, acc_s, wall_s = run(eng_s, False)
-box_o, acc_o, wall_o = run(eng_o, True)
-micro_parity = (tree_equal(box_s.payload, box_o.payload)
-                and tree_equal(box_s.valid, box_o.valid)
-                and all(float(a) == float(b) for a, b in zip(acc_s, acc_o)))
-pipe = summarize(eng_o.tracer)["pipeline"]
-micro = {"V": V, "cap": cap, "rounds": R, "parity": bool(micro_parity),
-         "wall_seq_s": wall_s, "wall_overlap_s": wall_o,
-         "hop_s": pipe["hop_s"], "compute_s": pipe["compute_s"],
-         "pipeline_wall_s": pipe["wall_s"],
-         "efficiency": pipe["overlap_efficiency"],
-         "overlapped_rounds": int(eng_o.route_log.overlapped)}
-
-# --- plan parity: sort + hull2d, overlapped vs sequential ----------------
-key = jax.random.PRNGKey(0)
-n = 128 * DEV
-x = jnp.asarray(rng.normal(size=n).astype(np.float32))
-pts = jnp.asarray(rng.normal(size=(n, 2)).astype(np.float32))
-plans = [("sort", sort_plan(n, 16, align=eng_o.aligned_nodes), (x,)),
-         ("hull2d", hull2d_plan(n, 16, align=eng_o.aligned_nodes), (pts,))]
-plan_rows = []
-for name, plan, args in plans:
-    exe_o, exe_s = eng_o.compile(plan), eng_s.compile(plan)
-    res_o = jax.block_until_ready(exe_o(*args, key=key))
-    res_s = jax.block_until_ready(exe_s(*args, key=key))
-    t0 = time.perf_counter()
-    jax.block_until_ready(exe_o(*args, key=key))
-    t_o = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    jax.block_until_ready(exe_s(*args, key=key))
-    t_s = time.perf_counter() - t0
-    plan_rows.append({"name": name, "parity": bool(tree_equal(res_s, res_o)),
-                      "wall_overlap_s": t_o, "wall_seq_s": t_s})
-
-print(json.dumps({"devices": DEV, "micro": micro, "plans": plan_rows}))
-"""
-
-
-def bench_scaling(quick):
-    """Weak-scaling grid for the double-buffered sharded schedule
-    (DESIGN.md §13): one subprocess per mesh size (jax pins the fake-CPU
-    device count at first init), each running (a) an R-round ring program
-    on ShardedEngine overlapped vs the ``overlap=False`` sequential
-    comparator and (b) the sort/hull2d plans, asserting bit-identical
-    mailboxes/outputs/CostAccum, and measuring how much of the calibrated
-    all_to_all hop cost the overlapped schedule hides under reducer
-    compute.  Gated series are the machine-independent parity/engagement
-    rates; wall times and hop-hidden fractions go under ``info``."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    sizes = [1, 2] if quick else [1, 2, 4, 8]
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    rows = []
-    for ndev in sizes:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={ndev}"
-        env["PYTHONPATH"] = os.path.join(repo, "src")
-        # Fake-device runs: pinned to the CPU, so a parent that holds the
-        # chip never starts a child that waits for it.
-        env["JAX_PLATFORMS"] = "cpu"
-        proc = subprocess.run([sys.executable, "-c", _SCALING_CHILD],
-                              capture_output=True, text=True, env=env,
-                              timeout=600)
-        assert proc.returncode == 0, proc.stderr[-4000:]
-        rows.append(json.loads(proc.stdout.splitlines()[-1]))
-
-    checks = [r["micro"]["parity"] for r in rows] + \
-             [p["parity"] for r in rows for p in r["plans"]]
-    assert all(checks), rows
-    engaged = [r["micro"]["overlapped_rounds"] > 0 for r in rows]
-    assert all(engaged), rows
-    # Acceptance: the hop is measurably hidden (overlapped window wall <
-    # calibrated sequential hop + compute sum) on >= 1 multi-device point.
-    multi = [r for r in rows if r["devices"] > 1]
-    assert any((r["micro"]["efficiency"] or 0.0) > 0.0 for r in multi), \
-        [(r["devices"], r["micro"]) for r in multi]
-
-    series = {
-        "scaling_parity_rate": sum(checks) / len(checks),
-        "scaling_overlap_engaged_rate": sum(engaged) / len(engaged),
-    }
-    info = {"grid": sizes, "rows_wall": [
-        {"devices": r["devices"],
-         "micro_wall_seq_s": r["micro"]["wall_seq_s"],
-         "micro_wall_overlap_s": r["micro"]["wall_overlap_s"],
-         "hop_s": r["micro"]["hop_s"],
-         "compute_s": r["micro"]["compute_s"],
-         "overlap_efficiency": r["micro"]["efficiency"],
-         "plans": r["plans"]} for r in rows]}
-    payload = {"bench": "scaling", "backend": "cpu",
-               "rounds": 16, "rows": rows, "series": series, "info": info}
-    with open("BENCH_scaling.json", "w", encoding="utf-8") as f:
-        json.dump(payload, f, indent=2)
-    for r in rows:
-        m = r["micro"]
-        eff = m["efficiency"] if m["efficiency"] is not None else 0.0
-        print(f"scaling_overlap_d{r['devices']},{m['wall_overlap_s']*1e6:.0f},"
-              f"devices={r['devices']}|seq_us={m['wall_seq_s']*1e6:.0f}"
-              f"|hop_hidden={eff:.2f}|parity={m['parity']}")
-    print(f"scaling_bench_json,0,wrote BENCH_scaling.json ({len(rows)} rows)")
-
-
 BENCHES = [bench_prefix_sums, bench_random_indexing, bench_multisearch,
            bench_sorting, bench_funnel, bench_queues, bench_shuffle,
            bench_kernels, bench_moe_dispatch, bench_geometry,
            bench_cost_model, bench_plan, bench_shape, bench_serve,
-           bench_faults, bench_obs, bench_scaling]
+           bench_faults, bench_obs]
 
 
 def main() -> None:

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 
 from .plan import Plan, execute_plan
 from ..obs import NULL_TRACER
+from ..obs.trace import annotate
 
 
 class CacheInfo(NamedTuple):
@@ -151,10 +152,12 @@ class Executable:
     def __call__(self, *inputs, key=None):
         tr = getattr(self.engine, "tracer", NULL_TRACER)
         if not tr.enabled:
-            return self._fn(key, *inputs)
+            with annotate("exe.call"):
+                return self._fn(key, *inputs)
         t0 = tr.clock()
         n0 = self._traces
-        out = self._fn(key, *inputs)
+        with annotate("exe.call"):
+            out = self._fn(key, *inputs)
         backend = getattr(self.engine, "name", "?")
         if self._traces > n0 and getattr(self.engine, "jittable", False):
             tr.event("exe.compile", plan=self.plan.name, backend=backend,

@@ -79,7 +79,7 @@ from .costmodel import CostAccum, MRCost, RoundStats
 from .mrmodel import Mailbox, Payload, RoundFn, make_mailbox
 from .mrmodel import shuffle as _dense_shuffle
 from ..obs import NULL_TRACER, round_event as _round_event
-from ..obs.trace import not_tracing
+from ..obs.trace import annotate
 
 
 class RoundProgram(NamedTuple):
@@ -211,20 +211,23 @@ class MREngine:
         round* when it differs from ``box.n_nodes`` (the paper's tree
         algorithms shrink their live node set geometrically per level;
         DESIGN.md §9).  ``f`` must then emit destinations in the target's
-        compact numbering [0, n_nodes).  None keeps the current shape."""
+        compact numbering [0, n_nodes).  None keeps the current shape.
+
+        ``f`` runs inside the ``mr.round`` named scope, and an eager round
+        inside an ``engine.round`` profiler annotation."""
         cap = capacity if capacity is not None else box.capacity
         V = n_nodes if n_nodes is not None else box.n_nodes
         tr = self.tracer
-        if not tr.enabled:
-            dests, payload = f(round_idx, self.node_ids(box.n_nodes), box)
-            return self.shuffle(dests, payload, V, cap)
-        # Traced (per-round) path: the event drops silently under jit/scan
-        # tracing, so the jitted round loop is untouched; on eager rounds
-        # reading the stats is a host sync — the opt-in cost of tracing.
-        t0 = tr.clock()
-        dests, payload = f(round_idx, self.node_ids(box.n_nodes), box)
-        out_box, stats = self.shuffle(dests, payload, V, cap)
-        _round_event(tr, t0, self.name, round_idx, V, cap, stats)
+        t0 = tr.clock() if tr.enabled else 0.0
+        with annotate("engine.round", round=round_idx):
+            with jax.named_scope("mr.round"):
+                dests, payload = f(round_idx, self.node_ids(box.n_nodes), box)
+            out_box, stats = self.shuffle(dests, payload, V, cap)
+        if tr.enabled:
+            # The event drops silently under jit/scan tracing, so the
+            # jitted round loop is untouched; on eager rounds reading the
+            # stats is a host sync — the opt-in cost of tracing.
+            _round_event(tr, t0, self.name, round_idx, V, cap, stats)
         return out_box, stats
 
     def run_rounds(self, f: RoundFn, box: Mailbox, n_rounds: int,
@@ -390,11 +393,10 @@ class LocalEngine(MREngine):
     them falls back to the bit-identical dense shuffle.  Every routing
     decision is counted in this engine's own ``route_log``
     (:class:`repro.core.kshuffle.RouteLog` — per-engine so concurrent
-    services on different engines cannot interleave counts; the
-    module-global :data:`repro.core.kshuffle.route_log` remains as a
-    deprecated process-wide aggregate) and, when a tracer is attached,
-    recorded as a ``shuffle.route`` trace event, so tests and benches can
-    assert the kernel path was actually taken.
+    services on different engines cannot interleave counts) and, when a
+    tracer is attached, recorded as a ``shuffle.route`` trace event, so
+    tests and benches can assert the kernel path was actually taken.
+    Either route runs inside the ``mr.shuffle`` named scope.
     """
 
     name = "local"
@@ -410,13 +412,11 @@ class LocalEngine(MREngine):
         self.use_scan = use_scan
         self.shuffle_impl = shuffle_impl
         from .kshuffle import RouteLog
-        #: per-engine routing counters (PR 9: the old module-global
-        #: route_log was shared mutable state across engines/threads)
+        #: this engine's routing counters
         self.route_log = RouteLog()
         if shuffle_impl == "kernel":
-            from .kshuffle import kernel_fits, kernel_shuffle, route_log
+            from .kshuffle import kernel_fits, kernel_shuffle
             self._kernel_fits = kernel_fits
-            self._global_route_log = route_log   # deprecated aggregate view
             self._shuffle_fn = kernel_shuffle
             self.name = "pallas"
         else:
@@ -431,11 +431,9 @@ class LocalEngine(MREngine):
             if self._kernel_fits(n, n_nodes):
                 impl = "kernel"
                 self.route_log.kernel += 1
-                self._global_route_log.kernel += 1
             else:
                 impl = "dense"
                 self.route_log.dense += 1
-                self._global_route_log.dense += 1
                 fn = _dense_shuffle      # per-stage guard: oversize -> dense
             tr = self.tracer
             if tr.enabled:
@@ -444,7 +442,8 @@ class LocalEngine(MREngine):
                 tr.trace_event("shuffle.route", impl=impl, n=n,
                                n_nodes=int(n_nodes), backend=self.name)
                 tr.metrics.counter(f"shuffle.route.{impl}").inc()
-        return fn(dests, payload, n_nodes, capacity)
+        with jax.named_scope("mr.shuffle"):
+            return fn(dests, payload, n_nodes, capacity)
 
     def run_rounds(self, f: RoundFn, box: Mailbox, n_rounds: int,
                    capacity: Optional[int] = None,
@@ -553,8 +552,11 @@ class ShardedEngine(MREngine):
     baked in at ``_build`` time), so in a shape-scheduled program the late
     shrinking levels route through the kernel scatter even when the entry
     level cannot, and every decision lands in this engine's own
-    ``route_log`` (plus the deprecated module-global aggregate
-    :data:`repro.core.kshuffle.route_log`).
+    ``route_log``.
+
+    The hop program is named ``mr_hop`` and its body runs in the ``mr.hop``
+    named scope; the scatter program is ``mr_scatter``, in ``mr.shuffle``.
+    So on a chip each program's name gives its layer.
     """
 
     name = "sharded"
@@ -578,14 +580,13 @@ class ShardedEngine(MREngine):
         self.shuffle_impl = shuffle_impl
         #: double-buffer rounds of early_dests stages (False = always run
         #: the strictly-sequential per-round schedule — the comparator the
-        #: parity tests and bench_scaling measure against)
+        #: parity tests measure against)
         self.overlap = overlap
         from .kshuffle import RouteLog
-        self.route_log = RouteLog()          # per-engine (PR 9 bugfix)
+        self.route_log = RouteLog()          # this engine's routing counters
         if shuffle_impl == "kernel":
-            from .kshuffle import kernel_fits, kernel_shuffle, route_log
+            from .kshuffle import kernel_fits, kernel_shuffle
             self._kernel_fits = kernel_fits
-            self._global_route_log = route_log   # deprecated aggregate view
             self._local_shuffle = kernel_shuffle
         else:
             self._local_shuffle = _dense_shuffle
@@ -602,7 +603,8 @@ class ShardedEngine(MREngine):
 
         axis = self.axis_name
 
-        def body(dests, *leaves):
+        @jax.named_scope("mr.hop")
+        def mr_hop(dests, *leaves):
             flat_dest = dests.reshape(-1).astype(jnp.int32)
             n_local = flat_dest.shape[0]
             local_dest, recv_flat = keyed_hop(dests, leaves, axis, n_nodes)
@@ -624,8 +626,8 @@ class ShardedEngine(MREngine):
         P = jax.sharding.PartitionSpec
         in_specs = (P(axis),) + (P(axis),) * n_leaves
         out_specs = (P(axis), [P(axis)] * n_leaves, P(), P())
-        return jax.jit(jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
-                                     out_specs=out_specs))
+        return jax.jit(jax.shard_map(mr_hop, mesh=self.mesh,
+                                     in_specs=in_specs, out_specs=out_specs))
 
     def _build_scatter(self, n_nodes: int, capacity: int, n_leaves: int,
                        use_kernel: bool):
@@ -639,7 +641,8 @@ class ShardedEngine(MREngine):
         local_v = n_nodes // self.n_shards
         local_shuffle = self._local_shuffle if use_kernel else _dense_shuffle
 
-        def body(local_dest, *recv_flat):
+        @jax.named_scope("mr.shuffle")
+        def mr_scatter(local_dest, *recv_flat):
             box, st = local_shuffle(local_dest, list(recv_flat), local_v,
                                     capacity)
             return (box.payload, box.valid,
@@ -652,7 +655,7 @@ class ShardedEngine(MREngine):
         # pallas_call outputs carry no varying-axes annotation; the body's
         # outputs have explicit per-shard specs, so skipping the check is
         # sound.
-        fn = jax.shard_map(body, mesh=self.mesh, in_specs=in_specs,
+        fn = jax.shard_map(mr_scatter, mesh=self.mesh, in_specs=in_specs,
                            out_specs=out_specs, check_vma=not use_kernel)
         donate = ()
         if self.mesh.devices.flat[0].platform != "cpu":
@@ -663,18 +666,9 @@ class ShardedEngine(MREngine):
 
     def shuffle(self, dests, payload: Payload, n_nodes: int,
                 capacity: int) -> Tuple[Mailbox, RoundStats]:
-        box, stats, _ = self._shuffle_phased(dests, payload, n_nodes,
-                                             capacity)
-        return box, stats
-
-    def _shuffle_phased(self, dests, payload: Payload, n_nodes: int,
-                        capacity: int, measure: bool = False
-                        ) -> Tuple[Mailbox, RoundStats, Tuple[float, float]]:
         """The two-phase Shuffle: issue the hop program, then the scatter
         program, without ever blocking the host (async dispatch queues
-        both).  ``measure=True`` blocks after each phase and returns the
-        measured (hop_s, scatter_s) wall seconds — the calibration probe
-        the overlapped scheduler runs once per window (DESIGN.md §13)."""
+        both)."""
         dests = jnp.asarray(dests)
         if n_nodes % self.n_shards:
             raise ValueError(
@@ -703,10 +697,8 @@ class ShardedEngine(MREngine):
             use_kernel = self._kernel_fits(n, n_nodes // self.n_shards)
             if use_kernel:
                 self.route_log.kernel += 1
-                self._global_route_log.kernel += 1
             else:
                 self.route_log.dense += 1
-                self._global_route_log.dense += 1
             tr = self.tracer
             if tr.enabled:
                 tr.trace_event("shuffle.route",
@@ -726,13 +718,7 @@ class ShardedEngine(MREngine):
         if hop is None:
             hop = cache.store(hop_key, self._build_hop(
                 n_nodes, dests.ndim, len(leaves)))
-        clock = self.tracer.clock
-        t0 = clock() if measure else 0.0
         local_dest, recv_flat, items_sent, max_sent = hop(dests, *leaves)
-        hop_s = 0.0
-        if measure:
-            jax.block_until_ready((local_dest, recv_flat))
-            hop_s = clock() - t0
         recv_sig = tuple((l.shape, str(l.dtype)) for l in recv_flat)
         sc_key = ("scatter", n_nodes, capacity, local_dest.shape, recv_sig,
                   use_kernel)
@@ -740,17 +726,12 @@ class ShardedEngine(MREngine):
         if sc is None:
             sc = cache.store(sc_key, self._build_scatter(
                 n_nodes, capacity, len(recv_flat), use_kernel))
-        t1 = clock() if measure else 0.0
         out_leaves, valid, max_received, dropped = sc(local_dest, *recv_flat)
-        scatter_s = 0.0
-        if measure:
-            jax.block_until_ready((out_leaves, valid))
-            scatter_s = clock() - t1
         stats = RoundStats(items_sent=items_sent, max_sent=max_sent,
                            max_received=max_received, dropped=dropped)
         box = Mailbox(payload=jax.tree_util.tree_unflatten(treedef, out_leaves),
                       valid=valid)
-        return box, stats, (hop_s, scatter_s)
+        return box, stats
 
     # -- overlapped (double-buffered) round scheduling — DESIGN.md §13 -------
     def run_rounds(self, f: RoundFn, box: Mailbox, n_rounds: int,
@@ -817,51 +798,35 @@ class ShardedEngine(MREngine):
         ``CostAccum`` is bit-identical to the sequential schedule (same
         values, same fold order).
 
-        With a live tracer the first round runs as a calibration probe —
-        blocked after fn, hop, and scatter to measure the un-overlapped
-        per-phase costs — then the rest of the window runs free; one
-        ``pipeline.overlap`` event carries the measured window wall time
-        next to the calibrated (hop_s, compute_s) so the hop-hidden
-        fraction is computable from the trace alone (``pipeline.hop``
-        marks each issued round without reading any device value)."""
+        No span here reads a device value or blocks: each round runs in an
+        ``engine.round`` profiler annotation, and with a live tracer each
+        issued round records a ``pipeline.hop`` event inside one
+        ``pipeline.overlap`` span over the host's issue of the window.
+        The device time of the hop itself is read from a profiler trace
+        (the ``mr_hop`` program)."""
         acc = accum if accum is not None else CostAccum.zero()
         tr = self.tracer
-        live = tr.enabled and not_tracing()
-        clock = tr.clock
-        t_start = clock() if live else 0.0
-        calibrated = not live
-        hop_s = compute_s = 0.0
         pending = []
         self.route_log.overlapped += len(window)
-        for fn, capacity, n_nodes, r in window:
-            cap = capacity if capacity is not None else box.capacity
-            V = n_nodes if n_nodes is not None else box.n_nodes
-            measure = not calibrated
-            t_f = clock() if measure else 0.0
-            dests, payload = fn(r, self.node_ids(box.n_nodes), box)
-            f_s = 0.0
-            if measure:
-                jax.block_until_ready((dests, payload))
-                f_s = clock() - t_f
-            box, st, spans = self._shuffle_phased(dests, payload, V, cap,
-                                                  measure=measure)
-            pending.append(st)
-            if measure:
-                calibrated = True
-                hop_s = spans[0]
-                compute_s = f_s + spans[1]
-            if live:
-                tr.event("pipeline.hop", round=int(r), n_nodes=int(V),
-                         capacity=int(cap), backend=self.name)
-                tr.count("pipeline.hops")
+        with tr.span("pipeline.overlap", rounds=len(window),
+                     backend=self.name):
+            for fn, capacity, n_nodes, r in window:
+                cap = capacity if capacity is not None else box.capacity
+                V = n_nodes if n_nodes is not None else box.n_nodes
+                with annotate("engine.round", round=r):
+                    with jax.named_scope("mr.round"):
+                        dests, payload = fn(r, self.node_ids(box.n_nodes),
+                                            box)
+                    box, st = self.shuffle(dests, payload, V, cap)
+                pending.append(st)
+                if tr.enabled:
+                    tr.event("pipeline.hop", round=int(r), n_nodes=int(V),
+                             capacity=int(cap), backend=self.name)
+                    tr.count("pipeline.hops")
+        if tr.enabled:
+            tr.count("pipeline.overlaps")
         for st in pending:
             acc = acc.add_round_stats(st)
-        if live:
-            jax.block_until_ready(box.valid)
-            tr.event("pipeline.overlap", _dur=clock() - t_start,
-                     rounds=len(window), backend=self.name,
-                     hop_s=hop_s, compute_s=compute_s)
-            tr.count("pipeline.overlaps")
         return box, acc
 
 

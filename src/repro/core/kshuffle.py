@@ -109,14 +109,6 @@ class RouteLog:
         return (self.kernel, self.dense)
 
 
-#: DEPRECATED process-wide aggregate of every engine's routing decisions
-#: (kept as a shim: engines still mirror their per-engine ``route_log``
-#: counts here, but concurrent engines interleave in it — prefer
-#: ``engine.route_log``, scoped per engine since PR 9).  reset() between
-#: probes when you do use it.
-route_log = RouteLog()
-
-
 def _misfit(n: int, n_nodes: int, tile_n: Optional[int]) -> Optional[str]:
     """Why a shuffle of ``n`` items into ``n_nodes`` nodes cannot take the
     kernel path, or None when it can."""

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from .costmodel import CostAccum
 from .mrmodel import Mailbox
 from ..obs import NULL_TRACER, plan_token, round_event as _round_event
-from ..obs.trace import not_tracing
+from ..obs.trace import annotate, not_tracing
 
 
 class PlanStage(NamedTuple):
@@ -229,6 +229,11 @@ def execute_plan(plan: Plan, engine, inputs: Tuple, key=None,
     safe under ``jax.jit`` / ``jax.vmap`` on array backends, which is what
     :class:`~repro.core.api.Executable` relies on for caching and batching.
 
+    The compiled program names its layers (``jax.named_scope``, read back
+    from each op's ``op_name``): ``mr.prologue`` and ``mr.epilogue`` around
+    the plan's two ends, and each stage's name around that stage (inside
+    it the engine's ``mr.round`` / ``mr.shuffle`` scopes).
+
     ``checkpointer`` (a :class:`repro.core.recovery.Checkpointer`) turns on
     the ``checkpoint_every`` policy: after each stage the full
     ``{"box", "carry", "accum"}`` state is offered to ``maybe_save`` at that
@@ -240,46 +245,48 @@ def execute_plan(plan: Plan, engine, inputs: Tuple, key=None,
     one."""
     _check_inputs(plan, inputs)
     keys = plan.split_key(key)
-    carry = plan.prologue(tuple(inputs), keys)
+    with jax.named_scope("mr.prologue"):
+        carry = plan.prologue(tuple(inputs), keys)
     state = PlanState(box=None, carry=carry, accum=CostAccum.zero())
+    tr = getattr(engine, "tracer", NULL_TRACER)
     if checkpointer is not None:
         from .recovery import _apply_stages
         state = _apply_stages(plan, engine, state, 0, checkpointer)
-    else:
-        tr = getattr(engine, "tracer", NULL_TRACER)
-        if tr.enabled and not_tracing():
-            # Eager traced execution: per-stage spans carry the declared
-            # schedule next to the measured CostAccum deltas (reading them
-            # is a host sync — the opt-in cost of tracing).  Under jit the
-            # spans would no-op, so the compiled Executable path takes the
-            # identical plain loop below.
-            state = _traced_stages(plan, engine, state, tr)
-        else:
+    elif tr.enabled and not_tracing():
+        with tr.span("plan.execute", plan=plan.name, digest=plan_token(plan),
+                     backend=getattr(engine, "name", "?")):
             for stage in plan.stages:
-                state = stage.apply(engine, state)
-    return plan.epilogue(state)
-
-
-def _traced_stages(plan: Plan, engine, state: PlanState, tr) -> PlanState:
-    """The observable stage loop of :func:`execute_plan`: one
-    ``plan.execute`` span wrapping one ``plan.stage`` span per stage, each
-    recording its measured round/communication/drop deltas so
-    :func:`repro.obs.summary.summarize` can check measured == declared."""
-    with tr.span("plan.execute", plan=plan.name, digest=plan_token(plan),
-                 backend=getattr(engine, "name", "?")):
+                state = apply_stage(plan, engine, stage, state, tr)
+    else:
         for stage in plan.stages:
-            r0 = int(state.accum.rounds)
-            c0 = float(state.accum.communication)
-            d0 = int(state.accum.dropped)
-            with tr.span("plan.stage", plan=plan.name, stage=stage.name,
-                         rounds=stage.rounds, capacity=stage.capacity,
-                         n_nodes=stage.n_nodes,
-                         shuffles=stage.shuffles) as sp:
-                state = stage.apply(engine, state)
-                sp["measured_rounds"] = int(state.accum.rounds) - r0
-                sp["items_sent"] = int(
-                    float(state.accum.communication) - c0)
-                sp["dropped"] = int(state.accum.dropped) - d0
+            state = apply_stage(plan, engine, stage, state, tr)
+    with jax.named_scope("mr.epilogue"):
+        return plan.epilogue(state)
+
+
+def apply_stage(plan: Plan, engine, stage: PlanStage, state: PlanState,
+                tr=NULL_TRACER) -> PlanState:
+    """Apply one stage inside its ``plan.stage`` span and its named scope.
+
+    Eager with a live tracer, the span records the stage's declared
+    schedule next to its measured round/communication/drop deltas (reading
+    them is a host sync — the opt-in cost of tracing), so
+    :func:`repro.obs.summary.summarize` can check measured == declared.
+    Otherwise the span only opens its profiler annotation (none at jax
+    trace time), and nothing is read from the device."""
+    with jax.named_scope(stage.name), \
+            tr.span("plan.stage", plan=plan.name, stage=stage.name,
+                    rounds=stage.rounds, capacity=stage.capacity,
+                    n_nodes=stage.n_nodes, shuffles=stage.shuffles) as sp:
+        if not (tr.enabled and not_tracing()):
+            return stage.apply(engine, state)
+        r0 = int(state.accum.rounds)
+        c0 = float(state.accum.communication)
+        d0 = int(state.accum.dropped)
+        state = stage.apply(engine, state)
+        sp["measured_rounds"] = int(state.accum.rounds) - r0
+        sp["items_sent"] = int(float(state.accum.communication) - c0)
+        sp["dropped"] = int(state.accum.dropped) - d0
     return state
 
 
@@ -311,8 +318,10 @@ def entry_stage(name: str, n_nodes: int, capacity: int,
         tr = getattr(engine, "tracer", NULL_TRACER)
         t0 = tr.clock() if tr.enabled else 0.0
         V = engine.aligned_nodes(n_nodes)
-        dests, payload = emit(state.carry)
-        box, st = engine.shuffle(dests, payload, V, capacity)
+        with annotate("engine.round", round=0):
+            with jax.named_scope("mr.round"):
+                dests, payload = emit(state.carry)
+            box, st = engine.shuffle(dests, payload, V, capacity)
         if tr.enabled:
             _round_event(tr, t0, getattr(engine, "name", "?"), 0,
                          V, capacity, st)
@@ -358,7 +367,8 @@ def compute_stage(name: str, fn: Callable) -> PlanStage:
     compute between shuffles (the paper's in-reducer work)."""
 
     def apply(engine, state: PlanState) -> PlanState:
-        box, carry = fn(state.box, state.carry)
+        with jax.named_scope("mr.round"):
+            box, carry = fn(state.box, state.carry)
         return state._replace(box=box, carry=carry)
 
     return PlanStage(name, 0, None, apply, shuffles=False)
@@ -375,8 +385,15 @@ def custom_stage(name: str, rounds: int, capacity: Optional[int],
     declarative here — the body drives its own shuffles).  ``early_dests``
     likewise only *declares* overlap legality (DESIGN.md §13): a custom
     body that wants the double-buffered schedule must itself pass the flag
-    to ``engine.run_rounds``/``run_stages``."""
-    return PlanStage(name, rounds, capacity, apply, n_nodes,
+    to ``engine.run_rounds``/``run_stages``.  The body runs inside the
+    ``mr.round`` scope; the engine's own ``mr.shuffle`` scope nests inside
+    it."""
+
+    def scoped(engine, state: PlanState) -> PlanState:
+        with jax.named_scope("mr.round"):
+            return apply(engine, state)
+
+    return PlanStage(name, rounds, capacity, scoped, n_nodes,
                      early_dests=early_dests)
 
 

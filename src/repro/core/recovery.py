@@ -75,7 +75,7 @@ import jax.numpy as jnp
 from .costmodel import CostAccum
 from .engine import MREngine, ShardedEngine
 from .mrmodel import Mailbox
-from .plan import Plan, PlanState
+from .plan import Plan, PlanState, apply_stage
 from ..obs import NULL_TRACER, Tracer, plan_token
 from ..train import checkpoint as _ckpt
 
@@ -569,24 +569,10 @@ def _wire_tracer(checkpointer: Optional[Checkpointer], tr) -> None:
 
 def _staged_apply(plan: Plan, engine, i: int, state: PlanState,
                   tr) -> PlanState:
-    """One stage application under an (optional) ``plan.stage`` span — the
-    eager-driver counterpart of ``plan._traced_stages``, recording the same
-    measured CostAccum deltas.  A stage killed mid-apply by an injected
-    fault records its span with ``aborted=True`` (see obs trace module)."""
-    stage = plan.stages[i]
-    if not tr.enabled:
-        return stage.apply(engine, state)
-    r0 = int(state.accum.rounds)
-    c0 = float(state.accum.communication)
-    d0 = int(state.accum.dropped)
-    with tr.span("plan.stage", plan=plan.name, stage=stage.name,
-                 rounds=stage.rounds, capacity=stage.capacity,
-                 n_nodes=stage.n_nodes, shuffles=stage.shuffles) as sp:
-        state = stage.apply(engine, state)
-        sp["measured_rounds"] = int(state.accum.rounds) - r0
-        sp["items_sent"] = int(float(state.accum.communication) - c0)
-        sp["dropped"] = int(state.accum.dropped) - d0
-    return state
+    """Stage ``i`` under its ``plan.stage`` span (:func:`repro.core.plan.
+    apply_stage`).  A stage killed mid-apply by an injected fault records
+    its span with ``aborted=True`` (see obs trace module)."""
+    return apply_stage(plan, engine, plan.stages[i], state, tr)
 
 
 def _apply_stages(plan: Plan, engine, state: PlanState, start: int,

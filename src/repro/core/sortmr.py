@@ -234,8 +234,9 @@ def sort_plan(n: int, M: int, *, dtype=jnp.float32, levels: int = 1,
         return max(1, int(math.ceil(slack * n / group_nodes(d))))
 
     def bucket_of(splitters, v):
-        b = jnp.searchsorted(splitters, v, side="left")
-        return jnp.clip(b, 0, V - 1).astype(jnp.int32)
+        with jax.named_scope("sort.lookup"):
+            b = jnp.searchsorted(splitters, v, side="left")
+            return jnp.clip(b, 0, V - 1).astype(jnp.int32)
 
     def level_dest(splitters, vals, valid, d):
         # Frozen numbering sends bucket group g to its leader node

@@ -15,11 +15,9 @@ use: did a refactor change round counts, communication, or wall time?).
 
 The report's ``pipeline`` section folds the ShardedEngine's
 double-buffered-round events (DESIGN.md §13): ``pipeline.hop`` marks each
-round issued through an overlapped window and ``pipeline.overlap`` carries
-the window's measured wall time next to the calibrated per-round
-(hop_s, compute_s) probe, from which :func:`summarize` derives
-``overlap_efficiency`` — the fraction of the all_to_all hop cost hidden
-under reducer compute.
+round issued through an overlapped window and the ``pipeline.overlap``
+span covers the host's issue of the window.  Neither blocks, so neither
+times the device: the hop's device time comes from a profiler trace.
 
 The trace → summary flow, end to end (an eager traced run records the
 full stage telemetry, and the schedule check passes):
@@ -80,7 +78,7 @@ def summarize(events) -> Dict[str, Any]:
                 "aborted_stages": 0}
     routes = {"kernel": 0, "dense": 0}
     pipeline = {"windows": 0, "overlapped_rounds": 0, "hops": 0,
-                "wall_s": 0.0, "hop_s": 0.0, "compute_s": 0.0}
+                "issue_s": 0.0}
     plans: Dict[str, Dict[str, Any]] = {}
     cache = {"hits": 0, "misses": 0, "compiles": 0, "exe_calls": 0}
 
@@ -131,11 +129,7 @@ def summarize(events) -> Dict[str, Any]:
             pipeline["windows"] += 1
             pipeline["overlapped_rounds"] += n
             if e.dur is not None:
-                pipeline["wall_s"] += e.dur
-            # Calibrated un-overlapped per-phase costs, scaled to the
-            # window: what the same rounds would cost strictly in sequence.
-            pipeline["hop_s"] += float(a.get("hop_s", 0.0) or 0.0) * n
-            pipeline["compute_s"] += float(a.get("compute_s", 0.0) or 0.0) * n
+                pipeline["issue_s"] += e.dur
         elif e.kind == "serve.submit":
             serve["submitted"] += 1
         elif e.kind == "serve.reject":
@@ -176,16 +170,6 @@ def summarize(events) -> Dict[str, Any]:
         rows.append(row)
     serve["mean_occupancy"] = (serve["occupancy"] / serve["dispatches"]
                                if serve["dispatches"] else None)
-    # Overlap efficiency: the fraction of the calibrated hop cost hidden
-    # under compute by the double-buffered schedule — (sequential estimate
-    # - measured overlapped wall) / hop cost, clamped to [0, 1].  None when
-    # no overlapped window ran (or the probe measured no hop cost).
-    if pipeline["windows"] and pipeline["hop_s"] > 0.0:
-        seq_est = pipeline["hop_s"] + pipeline["compute_s"]
-        hidden = (seq_est - pipeline["wall_s"]) / pipeline["hop_s"]
-        pipeline["overlap_efficiency"] = max(0.0, min(1.0, hidden))
-    else:
-        pipeline["overlap_efficiency"] = None
     return {
         "stages": rows,
         "plans": plans,
@@ -244,14 +228,10 @@ def format_table(summary: Dict[str, Any]) -> str:
                      f"dense={routes.get('dense', 0)}")
     pipe = summary.get("pipeline") or {}
     if pipe.get("windows"):
-        eff = pipe.get("overlap_efficiency")
-        eff_s = "n/a" if eff is None else f"{eff:.2f}"
         lines.append(
             f"pipeline: {pipe['windows']} overlapped windows "
             f"({pipe['overlapped_rounds']} rounds, {pipe['hops']} hops), "
-            f"wall {pipe['wall_s'] * 1e3:.2f} ms vs sequential est. "
-            f"{(pipe['hop_s'] + pipe['compute_s']) * 1e3:.2f} ms; "
-            f"overlap efficiency {eff_s}")
+            f"host issue {pipe['issue_s'] * 1e3:.2f} ms")
     return "\n".join(lines)
 
 

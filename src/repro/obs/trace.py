@@ -17,11 +17,18 @@ that every layer grown since PR 1 reports into —
 - ``exe.call`` / ``exe.compile`` / ``cache.hit`` / ``cache.miss`` from
   :mod:`repro.core.api` and ``MREngine.compile``;
 - ``shuffle.route`` from the kernel-vs-dense decision in
-  ``LocalEngine``/``ShardedEngine`` (the per-engine successor of the old
-  module-global ``kshuffle.route_log``);
+  ``LocalEngine``/``ShardedEngine`` (beside the engine's ``route_log``);
 - ``serve.*`` dispatch/queue/retry lifecycle from
   :class:`repro.serve.QueryService`;
 - ``fault.*`` / ``ckpt.*`` / ``recover.*`` from :mod:`repro.core.recovery`.
+
+**On the profiler's clock**: whenever jax is not tracing, every span —
+:data:`NULL_TRACER`'s included — also opens a
+``jax.profiler.TraceAnnotation`` of its kind, carrying its ``stage`` and
+``round`` attrs (:func:`annotate`), so a ``jax.profiler`` trace shows the
+program's host boundaries (``exe.call``, ``plan.stage``, ``engine.round``)
+beside the device's ops.  With no profiler running that is one inactive
+``TraceMe`` per span; only a live :class:`Tracer` records into its ring.
 
 **Zero overhead on jitted paths** is a hard contract: instrumentation lives
 at host boundaries only, the default hook is the no-op :data:`NULL_TRACER`,
@@ -32,7 +39,7 @@ tracer attached — outputs and :class:`~repro.core.costmodel.CostAccum`
 stay bit-identical (``tests/test_obs.py``).  The one deliberate exception
 is :meth:`Tracer.trace_event`, which records *at trace time* — that is the
 correct semantics for the kernel-vs-dense route decision, which fires once
-per traced shape exactly like the legacy ``route_log`` counters.
+per traced shape exactly like the engine's ``route_log`` counters.
 
 >>> tr = Tracer(clock=iter(range(100)).__next__)
 >>> with tr.span("plan.stage", plan="sort", stage="entry"):
@@ -56,15 +63,54 @@ import jax
 from .metrics import MetricsRegistry
 
 __all__ = ["TraceEvent", "Tracer", "NullTracer", "NULL_TRACER",
-           "plan_token", "round_event"]
+           "annotate", "plan_token", "round_event"]
 
 #: attrs inherited from the innermost enclosing span that sets them
 _CONTEXT_KEYS = ("plan", "stage", "digest")
+#: span attrs a profiler annotation carries
+_ANNOTATED_KEYS = ("stage", "round")
 
 
 def not_tracing() -> bool:
     """True when jax is NOT currently tracing (host/eager execution)."""
     return jax.core.trace_ctx.is_top_level()
+
+
+class _Annotation:
+    """A ``jax.profiler.TraceAnnotation`` opened only when jax is not
+    tracing (at trace time a host span would time the trace, not the
+    run)."""
+
+    __slots__ = ("kind", "attrs", "_me")
+
+    def __init__(self, kind: str, attrs: Dict[str, Any]):
+        self.kind = kind
+        self.attrs = attrs
+        self._me = None
+
+    def __setitem__(self, key: str, value) -> None:
+        pass
+
+    def __enter__(self) -> "_Annotation":
+        if not_tracing():
+            self._me = jax.profiler.TraceAnnotation(self.kind, **{
+                k: self.attrs[k] for k in _ANNOTATED_KEYS
+                if self.attrs.get(k) is not None})
+            self._me.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._me is not None:
+            self._me.__exit__(*exc)
+            self._me = None
+
+
+def annotate(kind: str, **attrs) -> _Annotation:
+    """A host span of ``kind`` on the profiler's clock only: where the ring
+    buffer records the same boundary as an event (``exe.call``,
+    ``engine.round``), or where no tracer is attached.  Reads no device
+    value and never blocks."""
+    return _Annotation(kind, attrs)
 
 
 class _AbstractValue(Exception):
@@ -126,9 +172,10 @@ class TraceEvent:
 
 class _Span:
     """Context manager recording a span event at exit; supports
-    ``sp["key"] = value`` to attach attrs discovered mid-span."""
+    ``sp["key"] = value`` to attach attrs discovered mid-span.  Also opens
+    the span's profiler annotation (:func:`annotate`)."""
 
-    __slots__ = ("_tracer", "kind", "attrs", "_t0", "_live")
+    __slots__ = ("_tracer", "kind", "attrs", "_t0", "_live", "_note")
 
     def __init__(self, tracer: "Tracer", kind: str, attrs: Dict[str, Any]):
         self._tracer = tracer
@@ -136,6 +183,7 @@ class _Span:
         self.attrs = attrs
         self._t0 = 0.0
         self._live = False
+        self._note = _Annotation(kind, attrs)
 
     def __setitem__(self, key: str, value) -> None:
         self.attrs[key] = value
@@ -145,6 +193,7 @@ class _Span:
         # frames a later eager event would inherit stale context from).
         self._live = not_tracing()
         if self._live:
+            self._note.__enter__()
             self._tracer._stack.append(self.attrs)
             self._t0 = self._tracer.clock()
         return self
@@ -152,6 +201,7 @@ class _Span:
     def __exit__(self, exc_type=None, *exc) -> None:
         if not self._live:
             return
+        self._note.__exit__(exc_type, *exc)
         tr = self._tracer
         tr._stack.pop()
         if exc_type is not None:
@@ -161,24 +211,6 @@ class _Span:
             self.attrs["aborted"] = True
         tr._record(self.kind, dur=tr.clock() - self._t0, attrs=self.attrs,
                    ts=self._t0)
-
-
-class _NullSpan:
-    """Shared no-op span of :class:`NullTracer`."""
-
-    __slots__ = ()
-
-    def __setitem__(self, key, value) -> None:
-        pass
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc) -> None:
-        pass
-
-
-_NULL_SPAN = _NullSpan()
 
 
 class Tracer:
@@ -288,9 +320,10 @@ class Tracer:
 
 class NullTracer:
     """The default hook: every recording method is a no-op and ``enabled``
-    is False, so instrumented call sites guard with one attribute read —
-    zero work, zero allocation on the hot path.  ``metrics`` is a shared
-    inert registry (guarded call sites never write it)."""
+    is False, so instrumented call sites guard with one attribute read.
+    Its spans record nothing but still open their profiler annotation
+    (:func:`annotate`).  ``metrics`` is a shared inert registry (guarded
+    call sites never write it)."""
 
     enabled = False
     metrics = MetricsRegistry()
@@ -301,8 +334,8 @@ class NullTracer:
     def trace_event(self, kind: str, **attrs) -> None:
         pass
 
-    def span(self, kind: str, **attrs) -> _NullSpan:
-        return _NULL_SPAN
+    def span(self, kind: str, **attrs) -> _Annotation:
+        return _Annotation(kind, attrs)
 
     def count(self, name: str, n: int = 1) -> None:
         pass

@@ -182,15 +182,15 @@ class TestGuardBoundaries:
     def test_multi_tile_path_actually_taken(self):
         """Regression: a shape past the old single-tile cliff must route
         through the kernel (route_log), not silently fall back to dense."""
-        from repro.core.kshuffle import _MAX_SORT_N, route_log
+        from repro.core.kshuffle import _MAX_SORT_N
         rng = np.random.default_rng(3)
         n, V, cap = _MAX_SORT_N + 64, 16, 20000
         dests = jnp.asarray(rng.integers(-1, V, n).astype(np.int32))
         payload = jnp.asarray(rng.normal(size=n).astype(np.float32))
         eng = get_engine("pallas")
-        route_log.reset()
+        eng.route_log.reset()
         got = eng.shuffle(dests, payload, V, cap)
-        assert route_log.snapshot() == (1, 0)
+        assert eng.route_log.snapshot() == (1, 0)
         assert_identical(LocalEngine().shuffle(dests, payload, V, cap), got,
                          ctx="past-old-cliff")
 
@@ -274,13 +274,13 @@ class TestShardedPerLevelRouting:
         small = jnp.asarray(rng.integers(-1, V, 64).astype(np.int32))
         eng = ShardedEngine(shuffle_impl="kernel")
         oracle = ShardedEngine()
-        K.route_log.reset()
+        eng.route_log.reset()
         for d in (big, small):
             p = jnp.arange(d.shape[0], dtype=jnp.float32)
             assert_identical(oracle.shuffle(d, p, V, cap),
                              eng.shuffle(d, p, V, cap),
                              ctx=f"n={d.shape[0]}")
-        assert K.route_log.snapshot() == (1, 1)
+        assert eng.route_log.snapshot() == (1, 1)
 
     def test_local_engine_per_call_guard(self, monkeypatch):
         """LocalEngine('pallas') falls back to dense past the budget and
@@ -292,13 +292,13 @@ class TestShardedPerLevelRouting:
         rng = np.random.default_rng(10)
         eng = get_engine("pallas")
         oracle = LocalEngine()
-        K.route_log.reset()
+        eng.route_log.reset()
         for n in (2 * tile, 64):
             d = jnp.asarray(rng.integers(-1, V, n).astype(np.int32))
             p = jnp.arange(n, dtype=jnp.float32)
             assert_identical(oracle.shuffle(d, p, V, cap),
                              eng.shuffle(d, p, V, cap), ctx=f"n={n}")
-        assert K.route_log.snapshot() == (1, 1)
+        assert eng.route_log.snapshot() == (1, 1)
 
 
 class TestEngineWiring:

@@ -13,8 +13,12 @@ Pins the DESIGN.md §12 contracts:
   the metrics registry snapshot schema, both exporters, the summary's
   measured-vs-declared schedule check, the serve dispatch causes and the
   per-plan ``max_wait_ms`` override, the Poisson open-loop arrivals, and
-  the per-engine ``route_log`` (the PR 9 bugfix) with its deprecated
-  module-global aggregate view.
+  the per-engine ``route_log``;
+- **layer names** — the compiled programs name their layers
+  (``jax.named_scope``: ``mr.prologue``, ``mr.round``, ``sort.lookup``,
+  ``mr.shuffle``, ``mr.hop``, ``mr.epilogue``), and the host spans
+  ``exe.call``, ``plan.stage`` and ``engine.round`` reach a
+  ``jax.profiler`` trace with or without a live tracer.
 """
 import json
 import pathlib
@@ -496,15 +500,13 @@ class TestPoissonOpenLoop:
 
 
 # ---------------------------------------------------------------------------
-# Per-engine route_log (PR 9 bugfix) + deprecated global shim
+# Per-engine route_log
 # ---------------------------------------------------------------------------
 
 class TestPerEngineRouteLog:
     def test_route_log_scoped_per_engine(self):
-        from repro.core.kshuffle import route_log as global_log
         e1 = get_engine("pallas")
         e2 = get_engine("pallas")
-        global_log.reset()
         dests = jnp.asarray(RNG.integers(0, 4, 16).astype(np.int32))
         vals = jnp.asarray(RNG.normal(size=16).astype(np.float32))
         e1.shuffle(dests, vals, 4, 8)
@@ -514,9 +516,6 @@ class TestPerEngineRouteLog:
         e2.shuffle(dests, vals, 4, 8)
         assert sum(e1.route_log.snapshot()) == 1
         assert sum(e2.route_log.snapshot()) == 2
-        # deprecated module global still aggregates across engines
-        assert sum(global_log.snapshot()) == 3
-        global_log.reset()
 
     def test_route_events_on_engine_tracer(self):
         tr = Tracer()
@@ -529,3 +528,140 @@ class TestPerEngineRouteLog:
         assert routes[0].attrs["impl"] in ("kernel", "dense")
         k, d = eng.route_log.snapshot()
         assert routes[0].attrs["impl"] == ("kernel" if k else "dense")
+
+
+# ---------------------------------------------------------------------------
+# Layer names in the compiled programs; host spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+#: the layer scopes every compiled sort plan carries
+_SORT_LAYERS = ("mr.prologue", "mr.round", "sort.lookup", "mr.shuffle",
+                "mr.epilogue")
+_SORT_N, _SORT_M = 256, 16
+
+
+def _op_names(hlo_text):
+    """Every ``op_name`` of an HLO text, split into its scope path."""
+    import re
+    return [n.split("/") for n in re.findall(r'op_name="([^"]*)"', hlo_text)]
+
+
+@pytest.fixture(scope="module")
+def sort_hlo():
+    """The compiled two-level sort plan's HLO text on ``local`` and on
+    ``pallas`` (kernels interpreted on the CPU), compiled once."""
+    out = {}
+    for name in ("local", "pallas"):
+        eng = get_engine(name)
+        plan = sort_plan(_SORT_N, _SORT_M, levels=2, align=eng.aligned_nodes)
+        exe = eng.compile(plan)
+        x = jnp.zeros((_SORT_N,), jnp.float32)
+        out[name] = (plan, exe._fn.lower(jax.random.PRNGKey(0), x)
+                     .compile().as_text())
+    return out
+
+
+class TestLayerScopes:
+    @pytest.mark.parametrize("layer", _SORT_LAYERS)
+    @pytest.mark.parametrize("name", ["local", "pallas"])
+    def test_sort_plan_ops_carry_each_layer(self, sort_hlo, name, layer):
+        _, text = sort_hlo[name]
+        assert any(layer in path for path in _op_names(text)), layer
+
+    @pytest.mark.parametrize("name", ["local", "pallas"])
+    def test_sort_plan_ops_carry_stage_names(self, sort_hlo, name):
+        plan, text = sort_hlo[name]
+        paths = _op_names(text)
+        shuffling = [st.name for st in plan.stages if st.shuffles]
+        assert shuffling == ["entry", "refine-1", "local-sort"]
+        for stage in shuffling:
+            # The stage scope encloses its engine layers.
+            under = [p for p in paths if stage in p]
+            assert any("mr.shuffle" in p[p.index(stage):] for p in under), \
+                stage
+        # The lookups happen in the two routing stages, inside a round.
+        lookup = {p[p.index("mr.round") - 1] for p in paths
+                  if "sort.lookup" in p and "mr.round" in p}
+        assert lookup == {"entry", "refine-1"}
+
+    def test_sharded_programs_name_hop_and_scatter(self):
+        eng = ShardedEngine()
+        V, cap, n = 8, 8, 32
+        dests = jnp.asarray(RNG.integers(-1, V, n).astype(np.int32))
+        vals = jnp.asarray(RNG.normal(size=n).astype(np.float32))
+        hop = eng._build_hop(V, dests.ndim, 1)
+        hop_text = hop.lower(dests, vals).compile().as_text()
+        local_dest, recv, _, _ = hop(dests, vals)
+        scatter = eng._build_scatter(V, cap, len(recv), False)
+        sc_text = scatter.lower(local_dest, *recv).compile().as_text()
+        assert hop_text.startswith("HloModule jit_mr_hop")
+        assert sc_text.startswith("HloModule jit_mr_scatter")
+        assert any("mr.hop" in p for p in _op_names(hop_text))
+        assert any("mr.shuffle" in p for p in _op_names(sc_text))
+
+
+def _profiled_sharded_sort(tracer):
+    """Host spans (name, stats) that a ``jax.profiler`` trace of one eager
+    ``sharded`` two-level sort holds, and the engine."""
+    import glob
+    import os
+    from jax.profiler import ProfileData
+    eng = ShardedEngine(tracer=tracer)
+    plan = sort_plan(128, 16, levels=2, align=eng.aligned_nodes)
+    exe = eng.compile(plan)
+    x = jnp.asarray(RNG.normal(size=128).astype(np.float32))
+    jax.block_until_ready(exe(x))          # compiles outside the trace
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            jax.block_until_ready(exe(x))
+        (path,) = glob.glob(os.path.join(d, "**", "*.xplane.pb"),
+                            recursive=True)
+        data = ProfileData.from_file(path)
+    spans = [(ev.name, dict(ev.stats)) for plane in data.planes
+             if not plane.name.startswith("/device:")
+             for line in plane.lines for ev in line.events
+             if ev.name in ("exe.call", "plan.stage", "engine.round")]
+    return spans, eng, plan
+
+
+class TestHostSpansOnProfiler:
+    @pytest.mark.parametrize("live", [False, True], ids=["null", "tracer"])
+    def test_eager_sharded_sort_writes_host_spans(self, live):
+        tracer = Tracer() if live else None
+        spans, eng, plan = _profiled_sharded_sort(tracer)
+        names = [n for n, _ in spans]
+        assert names.count("exe.call") == 1
+        stages = [st["stage"] for n, st in spans if n == "plan.stage"]
+        assert stages == [st.name for st in plan.stages]
+        rounds = [st for n, st in spans if n == "engine.round"]
+        # entry, refine-1 and local-sort each run one eager round
+        assert len(rounds) == 3
+        assert all("round" in st for st in rounds)
+        if live:
+            assert any(e.kind == "plan.stage" for e in eng.tracer.events())
+        else:
+            assert eng.tracer is NULL_TRACER
+
+    def test_no_profiler_null_tracer_records_nothing(self):
+        eng = ShardedEngine()
+        plan = sort_plan(64, 8, align=eng.aligned_nodes)
+        x = jnp.asarray(RNG.normal(size=64).astype(np.float32))
+        out = eng.compile(plan)(x)
+        assert int(out.stats.dropped) == 0
+        assert eng.tracer is NULL_TRACER
+        assert len(NULL_TRACER) == 0 and NULL_TRACER.events() == []
+        assert NULL_TRACER.metrics.snapshot()["counters"] == {}
+
+    def test_span_at_trace_time_opens_no_annotation(self):
+        seen = []
+
+        @jax.jit
+        def f(x):
+            with NULL_TRACER.span("plan.stage", stage="s") as sp:
+                seen.append(sp._me)
+            return x + 1
+
+        f(jnp.ones(2))
+        assert seen == [None]
+        with NULL_TRACER.span("plan.stage", stage="s") as sp:
+            assert sp._me is not None       # eager: the annotation is open

@@ -253,9 +253,9 @@ class TestKernelGuardFallback:
         eng = get_engine("pallas")
         dests = jnp.asarray(RNG.integers(0, V, n).astype(np.int32))
         payload = jnp.asarray(RNG.normal(size=n).astype(np.float32))
-        K.route_log.reset()
+        eng.route_log.reset()
         box_k, st_k = eng.shuffle(dests, payload, V, 4)
-        assert K.route_log.snapshot() == (0, 1)
+        assert eng.route_log.snapshot() == (0, 1)
         box_d, st_d = LocalEngine().shuffle(dests, payload, V, 4)
         np.testing.assert_array_equal(np.asarray(box_k.payload),
                                       np.asarray(box_d.payload))

@@ -7,10 +7,9 @@ stage table whose ``rounds`` column is the *measured* CostAccum delta and
 whose ``declared`` column is the plan's round-bound schedule — equal rows
 print ``OK``, so the paper's round bounds are checkable from telemetry
 alone.  Traces from a ShardedEngine overlapped run additionally print a
-``pipeline:`` footer with the overlap-efficiency figure (the fraction of
-the all_to_all hop cost hidden under reducer compute, computed from the
-measured ``pipeline.overlap`` window wall time against the calibrated
-hop/compute spans — DESIGN.md §13).  With ``--diff`` two traces are
+``pipeline:`` footer: windows, rounds and hops, and the host's issue time
+(DESIGN.md §13; the hop's device time comes from a profiler trace).  With
+``--diff`` two traces are
 compared stage by stage and semantic drift (round counts, communication,
 drops — never wall time) is flagged.
 
