@@ -26,6 +26,7 @@ redistribution (see repro.core.distributed.sharded_sample_sort).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple, Optional, Tuple
 
@@ -170,6 +171,69 @@ def quantile_splitters(x: jnp.ndarray, n_buckets: int, oversample: int,
     return sample[idx], s
 
 
+#: Most splitters the grouped lookup compares a key against (a lane width);
+#: a wider branching B keeps the binary search.
+GROUPED_LOOKUP_MAX = 128
+
+
+def _order_keys(x: jnp.ndarray) -> jnp.ndarray:
+    """Keys whose ``<`` is the total order ``jnp.sort`` and
+    ``jnp.searchsorted`` use: a float becomes a signed integer after -0.0
+    is made 0.0 and every NaN one NaN, which sorts last; an integer is its
+    own key."""
+    if not jnp.issubdtype(x.dtype, jnp.floating):
+        return x
+    x = jnp.where(x == 0, jnp.zeros_like(x), x)
+    x = jnp.where(jnp.isnan(x), jnp.full_like(x, jnp.nan), x)
+    itype = jnp.dtype(f"int{8 * x.dtype.itemsize}")
+    i = jax.lax.bitcast_convert_type(x, itype)
+    return jnp.where(i < 0, i ^ jnp.iinfo(itype).max, i)
+
+
+@functools.partial(jax.jit, static_argnames=("B", "width", "parent_width",
+                                              "compact"))
+def grouped_lookup(splitters, vals, valid, ids, *, B: int, width: int,
+                   parent_width: int, compact: bool) -> jnp.ndarray:
+    """Destinations of one sort level by its parent group's B-1 splitters.
+
+    A key whose bucket (``searchsorted(splitters, v)``) lies in parent
+    group p, i.e. in [p*parent_width, (p+1)*parent_width), has level group
+    p*B + #{k in 1..B-1 : splitters[(p*B + k)*width - 1] < v}: its bucket
+    passes the first bucket of child k iff that child's left splitter lies
+    below it.  A splitter index past the last reads as +inf.  Exact for any
+    V, duplicates included.
+
+    ``vals`` is ``(n,)`` at the entry level (``ids`` None: one root group)
+    or ``(rows, cap)`` in a mailbox whose row ``ids`` are the parent groups,
+    compactly numbered (``compact``) or frozen at their leader node
+    ``g * parent_width``.  The destination is the group, or its leader
+    ``group * width`` when frozen; -1 where ``valid`` is False."""
+    keys = _order_keys(jnp.asarray(vals))
+    spl = _order_keys(jnp.asarray(splitters))
+    spl = jnp.concatenate([spl, jnp.full((1,), jnp.iinfo(spl.dtype).max,
+                                         spl.dtype)])
+    if ids is None:
+        parent = jnp.zeros((1,), jnp.int32)
+    else:
+        parent = jnp.asarray(ids, jnp.int32)
+        if not compact:
+            parent = parent // parent_width
+    col = (-1,) + (1,) * (keys.ndim - 1)
+    k = jnp.arange(1, B, dtype=jnp.int32)
+    idx = jnp.minimum((parent[:, None] * B + k) * width - 1, spl.shape[0] - 1)
+    table = spl[idx]                                   # (rows, B-1)
+    group = jnp.broadcast_to(parent.reshape(col) * B, keys.shape)
+    for j in range(B - 1):                             # no (..., B-1) array
+        group = group + (table[:, j].reshape(col) < keys).astype(jnp.int32)
+    dest = group if compact else group * width
+    if valid is not None:
+        dest = jnp.where(valid, dest, -1)
+    # One pass in the mailbox's own layout: fused into the shuffle's
+    # flattening, the compiler would relayout each broadcast splitter
+    # column to the flat shape, and repeat the compares in every consumer.
+    return jax.lax.optimization_barrier(dest)
+
+
 def sort_plan(n: int, M: int, *, dtype=jnp.float32, levels: int = 1,
               oversample: Optional[int] = None, slack: float = 3.0,
               n_nodes: Optional[int] = None, align=None,
@@ -185,6 +249,17 @@ def sort_plan(n: int, M: int, *, dtype=jnp.float32, levels: int = 1,
     quantiles of a Theta(V * oversample) random sample — the paper's pivot
     stage, accounted as its O(log_M) rounds.  ``oversample`` defaults to
     :func:`default_oversample` of (V, slack).
+
+    The bucket lookup of each level is grouped (:func:`grouped_lookup`):
+    a key in mailbox row p already lies in level d-1 group p, so it is
+    compared with that group's B-1 child splitters only — one pass of
+    B-1 compares, not a binary search over all V-1 splitters — with the
+    entry level's single root group as p = 0.  That holds while B - 1 <=
+    ``GROUPED_LOOKUP_MAX`` (128, one lane width); a wider plan (only
+    ``levels=1`` with V > 129 in practice) keeps ``jnp.searchsorted``.
+    Either path gives the same destinations, and its scope
+    (``sort.lookup.grouped`` / ``sort.lookup.search``, inside
+    ``sort.lookup``) names it in the compiled program.
 
     Everything here is static — shapes, capacities, the stage table — so
     the plan is built **without touching data**; inputs ``(x,)`` arrive at
@@ -233,21 +308,28 @@ def sort_plan(n: int, M: int, *, dtype=jnp.float32, levels: int = 1,
     def group_cap(d):
         return max(1, int(math.ceil(slack * n / group_nodes(d))))
 
-    def bucket_of(splitters, v):
-        with jax.named_scope("sort.lookup"):
-            b = jnp.searchsorted(splitters, v, side="left")
-            return jnp.clip(b, 0, V - 1).astype(jnp.int32)
+    grouped = B - 1 <= GROUPED_LOOKUP_MAX
 
-    def level_dest(splitters, vals, valid, d):
+    def level_dest(splitters, vals, valid, ids, d):
         # Frozen numbering sends bucket group g to its leader node
         # g * width; the shape-scheduled ladder numbers level d's
         # min(V, B^(d+1)) live groups compactly (node g = group g) so the
         # mailbox carries no dead rows.  Same grouping either way — the
         # per-round stats are identical.
         width = B ** (levels - 1 - d)
-        group = bucket_of(splitters, vals) // width
+        with jax.named_scope("sort.lookup"):
+            if grouped:
+                with jax.named_scope("sort.lookup.grouped"):
+                    return grouped_lookup(splitters, vals, valid, ids, B=B,
+                                          width=width,
+                                          parent_width=width * B,
+                                          compact=shape)
+            with jax.named_scope("sort.lookup.search"):
+                b = jnp.searchsorted(splitters, vals, side="left")
+                bucket = jnp.clip(b, 0, V - 1).astype(jnp.int32)
+        group = bucket // width
         dest = group if shape else group * width
-        return jnp.where(valid, dest, -1)
+        return dest if valid is None else jnp.where(valid, dest, -1)
 
     def prologue(inputs, keys):
         x = jnp.asarray(inputs[0])
@@ -259,8 +341,8 @@ def sort_plan(n: int, M: int, *, dtype=jnp.float32, levels: int = 1,
         account_stage("pivot-sort", ((s, min(s, M_eff)),) * piv_rounds),
         # level 0 routes straight from the input collection
         entry_stage("entry", group_nodes(0) if shape else V, group_cap(0),
-                    lambda c: (level_dest(c["splitters"], c["x"],
-                                          jnp.ones_like(c["x"], bool), 0),
+                    lambda c: (level_dest(c["splitters"], c["x"], None,
+                                          None, 0),
                                c["x"])),
     ]
     for d in range(1, levels):
@@ -268,7 +350,7 @@ def sort_plan(n: int, M: int, *, dtype=jnp.float32, levels: int = 1,
             spl = carry["splitters"]
 
             def refine(r, ids, b):
-                return level_dest(spl, b.payload, b.valid, _d), b.payload
+                return level_dest(spl, b.payload, b.valid, ids, _d), b.payload
             return refine
         # early_dests: the refine ladder's group targets come from the
         # static level schedule (splitters are carry, not mailbox data) —

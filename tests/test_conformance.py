@@ -153,6 +153,30 @@ class TestAlgorithmConformance:
                                           err_msg=e.name)
             assert_same_accum(results[0].stats, res.stats, ctx=e.name)
 
+    # Bucket-refinement ladders whose branching B > 2 does not divide V - 1
+    # (V = 50, B = 8; V = 100, B = 5), float keys and int keys with
+    # duplicates: every level's grouped lookup, on every substrate, gives
+    # the ReferenceEngine's values and per-round stats.
+    @pytest.mark.parametrize("seed,n,M,levels,dtype", [
+        (5, 400, 8, 2, np.float32), (6, 400, 4, 3, np.int32)])
+    def test_sort_ladder_instances(self, seed, n, M, levels, dtype):
+        from repro.core import sort_plan
+        rng = np.random.default_rng(seed)
+        if dtype == np.int32:
+            x = jnp.asarray(rng.integers(0, n // 3, n).astype(dtype))
+        else:
+            x = jnp.asarray(rng.normal(size=n).astype(dtype))
+        key = jax.random.PRNGKey(seed)
+        results = [e.compile(sort_plan(n, M, dtype=x.dtype, levels=levels,
+                                       align=e.aligned_nodes))(x, key=key)
+                   for e in instance_engines()]
+        want = np.sort(np.asarray(x))
+        for res, e in zip(results, instance_engines()):
+            assert int(res.stats.dropped) == 0, e.name
+            np.testing.assert_array_equal(np.asarray(res.values), want,
+                                          err_msg=e.name)
+            assert_same_accum(results[0].stats, res.stats, ctx=e.name)
+
     @pytest.mark.parametrize("seed,n,M", [(3, 120, 8), (4, 250, 32)])
     def test_hull2d_instances(self, seed, n, M):
         rng = np.random.default_rng(seed)
