@@ -98,10 +98,12 @@ def sort_plan_escalating(x: jnp.ndarray, M: int, *, key=None,
                          engine=None) -> "EngineSortResult":
     """Run the sort plan, retrying the w.h.p. drop event with more capacity
     the way the paper does: defaults -> generous slack -> one reducer
-    (cap >= n, cannot drop).  Deterministic success even on all-duplicate
-    inputs.  The one escalate-until-no-drops policy — shared by the
-    deprecated ``sample_sort`` and the data pipeline's paper shuffle.
-    Host-level (reads ``stats.dropped``): not for use under jit."""
+    (cap >= n, cannot drop).  Duplicate-heavy inputs no longer need the
+    retries: the plan spreads keys that tie a splitter over every bucket
+    their value spans, so such inputs succeed at the first rung.  The one
+    escalate-until-no-drops policy — shared by the deprecated
+    ``sample_sort`` and the data pipeline's paper shuffle.  Host-level
+    (reads ``stats.dropped``): not for use under jit."""
     if engine is None:
         from .engine import default_engine
         engine = default_engine()
@@ -190,18 +192,84 @@ def _order_keys(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(i < 0, i ^ jnp.iinfo(itype).max, i)
 
 
+def splitter_runs(splitters) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(first, last): for each splitter index j, the first and the last
+    index of the run of splitters equal to ``splitters[j]`` (equal in the
+    order ``jnp.sort`` uses).  Keys equal to that value may go to any
+    bucket in [first, last + 1].  O(V), no search."""
+    s = _order_keys(jnp.asarray(splitters))
+    m = s.shape[0]
+    j = jnp.arange(m, dtype=jnp.int32)
+    if m == 0:
+        return j, j
+    step = s[1:] != s[:-1]
+    one = jnp.ones((1,), bool)
+    first = jax.lax.cummax(jnp.where(jnp.concatenate([one, step]), j, 0))
+    last = jax.lax.cummin(jnp.where(jnp.concatenate([step, one]), j, m - 1),
+                          reverse=True)
+    return first, last
+
+
+def _mix32(x: jnp.ndarray) -> jnp.ndarray:
+    """A 32-bit integer hash (lowbias32): every output bit depends on every
+    input bit."""
+    x = x.astype(jnp.uint32)
+    x = x ^ (x >> 16)
+    x = x * jnp.uint32(0x7FEB352D)
+    x = x ^ (x >> 15)
+    x = x * jnp.uint32(0x846CA68B)
+    return x ^ (x >> 16)
+
+
+def _positions(shape, parent: Optional[jnp.ndarray]) -> jnp.ndarray:
+    """Where each item already is: its input index at the entry level
+    (``parent`` None), else parent group * capacity + slot in the mailbox."""
+    if parent is None:
+        return jnp.arange(shape[0], dtype=jnp.int32)
+    slot = jnp.arange(shape[1], dtype=jnp.int32)
+    return parent[:, None] * shape[1] + slot[None, :]
+
+
+def _tied_bucket(lo, hi, tie, parent, parent_width: int) -> jnp.ndarray:
+    """Bucket ``lo``; where ``tie``, a bucket of [lo, hi] within the parent
+    group (``parent`` None: the root), uniform by a hash of the key's
+    position (:func:`_positions`)."""
+    with jax.named_scope("sort.lookup.ties"):
+        p_lo = 0 if parent is None else (parent * parent_width)[:, None]
+        first = jnp.maximum(lo, p_lo)
+        span = jnp.minimum(hi, p_lo + parent_width - 1) - first + 1
+        pos = _positions(lo.shape, parent)
+        u = (_mix32(pos) >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+        off = (u * span.astype(jnp.float32)).astype(jnp.int32)
+        return jnp.where(tie, first + jnp.minimum(off, span - 1), lo)
+
+
 @functools.partial(jax.jit, static_argnames=("B", "width", "parent_width",
                                               "compact"))
-def grouped_lookup(splitters, vals, valid, ids, *, B: int, width: int,
-                   parent_width: int, compact: bool) -> jnp.ndarray:
+def grouped_lookup(splitters, first, last, vals, valid, ids, *, B: int,
+                   width: int, parent_width: int,
+                   compact: bool) -> jnp.ndarray:
     """Destinations of one sort level by its parent group's B-1 splitters.
 
-    A key whose bucket (``searchsorted(splitters, v)``) lies in parent
-    group p, i.e. in [p*parent_width, (p+1)*parent_width), has level group
-    p*B + #{k in 1..B-1 : splitters[(p*B + k)*width - 1] < v}: its bucket
-    passes the first bucket of child k iff that child's left splitter lies
-    below it.  A splitter index past the last reads as +inf.  Exact for any
-    V, duplicates included.
+    Value v may go to any bucket in [#(splitters < v), #(splitters <= v)]:
+    those buckets concatenate to the sorted output whichever of them each
+    copy takes.  A key that equals no splitter has one such bucket, and
+    its group is that of ``searchsorted(splitters, v)``.  A key that equals
+    a splitter takes a bucket of its range uniformly, by a hash of where
+    it already is (:func:`_positions`), so a value with more copies than a
+    bucket holds is spread over every bucket its value spans.
+
+    Parent group p holds buckets [p*parent_width, (p+1)*parent_width); its
+    boundary k in 0..B is splitter index (p*B + k)*width - 1 (0 and B are
+    p's own edges; an index outside the splitters reads as a value of its
+    own past every key).  Two prefix sums over the B-1 child boundaries,
+    each one compare a boundary, give ``lo``: the start (``first``, from
+    :func:`splitter_runs`) of the run of the first boundary not below v,
+    and ``hi``: the end (``last``) of the run of the last boundary not
+    above v.  ``lo <= hi`` iff v equals a child boundary; then v's range
+    within p is [max(lo, p's first bucket), min(hi + 1, p's last)].
+    Otherwise bucket ``lo`` lies in v's child group, which is
+    ``lo // width``.
 
     ``vals`` is ``(n,)`` at the entry level (``ids`` None: one root group)
     or ``(rows, cap)`` in a mailbox whose row ``ids`` are the parent groups,
@@ -210,6 +278,7 @@ def grouped_lookup(splitters, vals, valid, ids, *, B: int, width: int,
     ``group * width`` when frozen; -1 where ``valid`` is False."""
     keys = _order_keys(jnp.asarray(vals))
     spl = _order_keys(jnp.asarray(splitters))
+    m = spl.shape[0]
     spl = jnp.concatenate([spl, jnp.full((1,), jnp.iinfo(spl.dtype).max,
                                          spl.dtype)])
     if ids is None:
@@ -219,12 +288,25 @@ def grouped_lookup(splitters, vals, valid, ids, *, B: int, width: int,
         if not compact:
             parent = parent // parent_width
     col = (-1,) + (1,) * (keys.ndim - 1)
-    k = jnp.arange(1, B, dtype=jnp.int32)
-    idx = jnp.minimum((parent[:, None] * B + k) * width - 1, spl.shape[0] - 1)
-    table = spl[idx]                                   # (rows, B-1)
-    group = jnp.broadcast_to(parent.reshape(col) * B, keys.shape)
-    for j in range(B - 1):                             # no (..., B-1) array
-        group = group + (table[:, j].reshape(col) < keys).astype(jnp.int32)
+    k = jnp.arange(B + 1, dtype=jnp.int32)
+    j = (parent[:, None] * B + k) * width - 1          # (rows, B+1)
+    real = (j >= 0) & (j < m)
+    at = jnp.clip(j, 0, m)
+    pad = jnp.full((1,), m, jnp.int32)
+    run_lo = jnp.where(real, jnp.concatenate([first, pad])[at], j)
+    run_hi = jnp.where(real, jnp.concatenate([last, pad])[at], j)
+    table = spl[at[:, 1:B]]                            # (rows, B-1)
+    step_lo = run_lo[:, 2:] - run_lo[:, 1:B]
+    step_hi = jnp.where(real[:, 1:B], run_hi[:, 1:B] - run_hi[:, :B - 1], 0)
+    lo = jnp.broadcast_to(run_lo[:, 1].reshape(col), keys.shape)
+    hi = jnp.broadcast_to(run_hi[:, 0].reshape(col), keys.shape)
+    for c in range(B - 1):                             # no (..., B-1) array
+        t = table[:, c].reshape(col)
+        lo = lo + jnp.where(t < keys, step_lo[:, c].reshape(col), 0)
+        hi = hi + jnp.where(t <= keys, step_hi[:, c].reshape(col), 0)
+    bucket = _tied_bucket(lo, hi + 1, lo <= hi,
+                          None if ids is None else parent, parent_width)
+    group = bucket // width
     dest = group if compact else group * width
     if valid is not None:
         dest = jnp.where(valid, dest, -1)
@@ -261,12 +343,22 @@ def sort_plan(n: int, M: int, *, dtype=jnp.float32, levels: int = 1,
     (``sort.lookup.grouped`` / ``sort.lookup.search``, inside
     ``sort.lookup``) names it in the compiled program.
 
+    A key that equals a splitter may lie in any bucket from
+    #(splitters < v) to #(splitters <= v); each level sends it to a
+    bucket of that range within its parent group, uniformly by a hash of
+    its input index (entry level) or of its mailbox row and slot, with
+    no payload added (scope ``sort.lookup.ties``).  So a value with more
+    copies than a bucket holds is spread over the buckets its value
+    spans, and a key that equals no splitter goes where the binary
+    search sends it.
+
     Everything here is static — shapes, capacities, the stage table — so
     the plan is built **without touching data**; inputs ``(x,)`` arrive at
     execute time.  ``align`` (e.g. ``engine.aligned_nodes``) rounds the
     default reducer count to a backend's layout granularity.  The executed
-    result is valid iff ``stats.dropped == 0`` (the paper's w.h.p. event —
-    raise ``slack`` or ``oversample`` if it fires).
+    result is valid iff ``stats.dropped == 0``: the paper's w.h.p. event,
+    a bucket of the sample's quantiles past ``slack`` times its share,
+    skewed and duplicated keys included.
 
     ``shape=True`` (default) shape-schedules the merge ladder (DESIGN.md
     §9): refinement level d runs in a physical mailbox of
@@ -310,7 +402,7 @@ def sort_plan(n: int, M: int, *, dtype=jnp.float32, levels: int = 1,
 
     grouped = B - 1 <= GROUPED_LOOKUP_MAX
 
-    def level_dest(splitters, vals, valid, ids, d):
+    def level_dest(c, vals, valid, ids, d):
         # Frozen numbering sends bucket group g to its leader node
         # g * width; the shape-scheduled ladder numbers level d's
         # min(V, B^(d+1)) live groups compactly (node g = group g) so the
@@ -320,37 +412,44 @@ def sort_plan(n: int, M: int, *, dtype=jnp.float32, levels: int = 1,
         with jax.named_scope("sort.lookup"):
             if grouped:
                 with jax.named_scope("sort.lookup.grouped"):
-                    return grouped_lookup(splitters, vals, valid, ids, B=B,
+                    return grouped_lookup(c["splitters"], c["first"],
+                                          c["last"], vals, valid, ids, B=B,
                                           width=width,
                                           parent_width=width * B,
                                           compact=shape)
             with jax.named_scope("sort.lookup.search"):
-                b = jnp.searchsorted(splitters, vals, side="left")
-                bucket = jnp.clip(b, 0, V - 1).astype(jnp.int32)
-        group = bucket // width
+                lo = jnp.searchsorted(c["splitters"], vals, side="left")
+                hi = jnp.searchsorted(c["splitters"], vals, side="right")
+                parent = None
+                if ids is not None:
+                    parent = jnp.asarray(ids, jnp.int32) // (
+                        1 if shape else width * B)
+                bucket = _tied_bucket(lo, hi, hi > lo, parent, width * B)
+        group = jnp.clip(bucket, 0, V - 1).astype(jnp.int32) // width
         dest = group if shape else group * width
         return dest if valid is None else jnp.where(valid, dest, -1)
 
     def prologue(inputs, keys):
         x = jnp.asarray(inputs[0])
         splitters, _ = quantile_splitters(x, V, oversample, keys["splitters"])
-        return {"x": x, "splitters": splitters}
+        carry = {"x": x, "splitters": splitters}
+        if grouped:
+            carry["first"], carry["last"] = splitter_runs(splitters)
+        return carry
 
     stages = [
         # pivot sort: O(log_M s) rounds moving the s samples
         account_stage("pivot-sort", ((s, min(s, M_eff)),) * piv_rounds),
         # level 0 routes straight from the input collection
         entry_stage("entry", group_nodes(0) if shape else V, group_cap(0),
-                    lambda c: (level_dest(c["splitters"], c["x"], None,
-                                          None, 0),
+                    lambda c: (level_dest(c, c["x"], None, None, 0),
                                c["x"])),
     ]
     for d in range(1, levels):
         def make_refine(carry, _d=d):
-            spl = carry["splitters"]
-
             def refine(r, ids, b):
-                return level_dest(spl, b.payload, b.valid, ids, _d), b.payload
+                return (level_dest(carry, b.payload, b.valid, ids, _d),
+                        b.payload)
             return refine
         # early_dests: the refine ladder's group targets come from the
         # static level schedule (splitters are carry, not mailbox data) —

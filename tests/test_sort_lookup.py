@@ -1,10 +1,12 @@
 """The sort plan's grouped bucket lookup (``sortmr.grouped_lookup``).
 
 A key in mailbox row p (its level d-1 bucket group) is compared with only
-that group's B-1 child splitters.  These tests hold it to the binary search
-it replaced — ``searchsorted`` over all V-1 splitters, then the division by
-the level's group width — at every level, and check which path a plan's
-compiled program takes.
+that group's B-1 child splitters.  These tests hold it, at every level, to
+the binary search it replaced — ``searchsorted`` over all V-1 splitters,
+then the division by the level's group width — for every key that equals
+no splitter, and to the key's legal range for a key that does: any bucket
+in [#(splitters < v), #(splitters <= v)] within its parent group.  They
+also check which path a plan's compiled program takes.
 """
 import math
 import re
@@ -15,7 +17,8 @@ import numpy as np
 import pytest
 
 from repro.core import LocalEngine, sort_plan
-from repro.core.sortmr import GROUPED_LOOKUP_MAX, grouped_lookup
+from repro.core.sortmr import (GROUPED_LOOKUP_MAX, grouped_lookup,
+                               splitter_runs)
 
 
 def _branching(V, levels):
@@ -46,11 +49,20 @@ def _splitters(x, V, rng):
 
 
 def _want(splitters, vals, valid, V, width, compact):
+    """The binary search's destinations: the group of bucket
+    ``searchsorted(splitters, v, side="left")``."""
     bucket = jnp.clip(jnp.searchsorted(splitters, jnp.asarray(vals),
                                        side="left"), 0, V - 1)
     group = np.asarray(bucket).astype(np.int64) // width
     dest = group if compact else group * width
     return dest if valid is None else np.where(valid, dest, -1)
+
+
+def _legal(splitters, vals):
+    """Each key's legal buckets [#(splitters < v), #(splitters <= v)]."""
+    vals = jnp.asarray(vals)
+    return (np.asarray(jnp.searchsorted(splitters, vals, side="left")),
+            np.asarray(jnp.searchsorted(splitters, vals, side="right")))
 
 
 def _mailbox(x, bucket, parent_width, rows, compact, rng):
@@ -68,6 +80,29 @@ def _mailbox(x, bucket, parent_width, rows, compact, rng):
         valid[r, fill[r]] = True
         fill[r] += 1
     return vals, valid
+
+
+def assert_lookup_contract(got, spl, vals, valid, ids, V, width, B,
+                           compact):
+    """Destinations equal the binary search's where the key ties no
+    splitter, and lie in the key's legal range within its parent group
+    where it does; -1 where ``valid`` is False."""
+    got = np.asarray(got).astype(np.int64)
+    vals = np.asarray(vals)
+    lo, hi = _legal(spl, vals)
+    live = np.ones(vals.shape, bool) if valid is None else valid
+    tied = (hi > lo) & live
+    want = _want(spl, vals, valid, V, width, compact)
+    np.testing.assert_array_equal(got[~tied], want[~tied])
+    pw = width * B
+    if ids is not None:
+        parent = np.asarray(ids, np.int64) // (1 if compact else pw)
+        lo = np.maximum(lo, parent[:, None] * pw)
+        hi = np.minimum(hi, parent[:, None] * pw + pw - 1)
+    group = got if compact else got // width
+    assert np.all(lo[tied] // width <= group[tied])
+    assert np.all(group[tied] <= hi[tied] // width)
+    return int(tied.sum())
 
 
 # (V, levels, shape, keys, extra rows): V not a power of B, V-1 not a
@@ -88,28 +123,46 @@ CASES = [
 @pytest.mark.parametrize("V,levels,shape,kind,extra", CASES)
 def test_grouped_lookup_equals_search_every_level(V, levels, shape, kind,
                                                   extra):
+    """Bit-identical to the binary search for keys that tie no splitter,
+    in the legal range for those that do.  Each level's mailbox puts every
+    key in the parent of a bucket drawn from its whole legal range, so
+    tied keys arrive on both sides of a parent boundary."""
     rng = np.random.default_rng(V + levels + extra)
     B = _branching(V, levels)
     assert B - 1 <= GROUPED_LOOKUP_MAX
     x = _keys(kind, 6000, rng)
     spl = _splitters(x, V, rng)
-    bucket = np.asarray(jnp.searchsorted(spl, jnp.asarray(x), side="left"))
+    first, last = splitter_runs(spl)
+    lo, hi = _legal(spl, x)
+    bucket = rng.integers(lo, hi + 1)
+    tied = 0
     for d in range(levels):
         width = B ** (levels - 1 - d)
         kw = dict(B=B, width=width, parent_width=width * B, compact=shape)
         if d == 0:
-            got = grouped_lookup(spl, x, None, None, **kw)
-            want = _want(spl, x, None, V, width, shape)
+            vals, valid, ids = x, None, None
         else:
             groups = -(-V // (width * B))
             rows = (groups if shape else V) + extra
             vals, valid = _mailbox(x, bucket, width * B, rows, shape, rng)
             ids = np.arange(rows, dtype=np.int32)
-            got = grouped_lookup(spl, vals, valid, ids, **kw)
-            want = _want(spl, vals, valid, V, width, shape)
+        got = grouped_lookup(spl, first, last, vals, valid, ids, **kw)
         assert got.dtype == jnp.int32
-        np.testing.assert_array_equal(np.asarray(got), want,
-                                      err_msg=f"level {d}")
+        tied += assert_lookup_contract(got, spl, vals, valid, ids, V, width,
+                                       B, shape)
+    if kind == "dup_int":
+        assert tied > 0
+
+
+def test_splitter_runs():
+    spl = jnp.asarray([1, 3, 3, 3, 5, 7, 7], jnp.int32)
+    first, last = splitter_runs(spl)
+    np.testing.assert_array_equal(np.asarray(first), [0, 1, 1, 1, 4, 5, 5])
+    np.testing.assert_array_equal(np.asarray(last), [0, 3, 3, 3, 4, 6, 6])
+    f = jnp.asarray([-0.0, 0.0, 1.0, jnp.nan, jnp.nan], jnp.float32)
+    first, last = splitter_runs(f)
+    np.testing.assert_array_equal(np.asarray(first), [0, 0, 2, 3, 3])
+    np.testing.assert_array_equal(np.asarray(last), [1, 1, 2, 4, 4])
 
 
 def _lookup_paths(n, M, levels):
